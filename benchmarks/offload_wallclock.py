@@ -420,6 +420,9 @@ print(json.dumps(out))
 
 def _run_child(code: str, timeout: int = 570, x64: bool = True) -> dict:
     env = dict(os.environ)
+    # the children measure the 8-virtual-device CPU substrate; pinning them
+    # to the CPU keeps them off an accelerator the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     # paper jobs are float64; the serving model stack is 32-bit only
     env["JAX_ENABLE_X64"] = "true" if x64 else "false"

@@ -255,6 +255,9 @@ print(json.dumps({"cold_us": cold_us, "off_us": off_us, "on_us": on_us}))
 
 def _overhead_rows() -> Tuple[List[Row], dict]:
     env = dict(os.environ)
+    # an 8-virtual-device CPU measurement: pinned to the CPU so it never
+    # contends for an accelerator the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["JAX_ENABLE_X64"] = "true"
     env["PYTHONPATH"] = (str(_ROOT / "src") + os.pathsep

@@ -8,15 +8,13 @@ device-to-device forward per edge.  Finally it seeds a defect (a
 dependency cycle) and shows the submit gate rejecting it *before* any
 staging, with a stable ``OFL001`` diagnostic.
 
-    PYTHONPATH=src python examples/job_graph.py
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        JAX_ENABLE_X64=true PYTHONPATH=src python examples/job_graph.py
 
 The graph builders are imported by ``make verify-graphs`` (the
 zero-diagnostics gate over every checked-in graph), so they construct
 nodes without touching devices.
 """
-
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import numpy as np
 

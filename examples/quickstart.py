@@ -3,15 +3,18 @@
 Drives the paper's AXPY kernel through the session API (typed policies,
 one submit path, ``policy=AUTO`` model-driven mode selection), compares
 both offload implementations' collective structure, and asks the
-analytical model for the optimal offload width.
+analytical model for the optimal offload width.  It offloads onto every
+local device, up to 8 clusters.  On the CPU, 8 virtual devices and the
+paper's float64:
 
-    PYTHONPATH=src python examples/quickstart.py
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        JAX_ENABLE_X64=true PYTHONPATH=src python examples/quickstart.py
+
+On a TPU host, ``PYTHONPATH=src python examples/quickstart.py`` uses its
+chips, in float32 (x64 off).
 """
 
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_ENABLE_X64", "true")
-
+import jax
 import numpy as np
 
 from repro.api import (
@@ -22,45 +25,55 @@ from repro.core.multicast import CLUSTER_OFFSET_BITS, MulticastRequest
 from repro.core.offload import count_collectives
 
 
+def close(got, want) -> bool:
+    """Normwise agreement with numpy's float64 result; with x64 off the
+    job runs in float32, ~1e-7 relative error."""
+    return bool(np.linalg.norm(np.asarray(got) - want)
+                <= 1e-6 * np.linalg.norm(want))
+
+
 def main() -> None:
     job = jobs.make_axpy(4096)
-    sess = Session()          # every local "cluster"; default policy=AUTO
+    n = min(len(jax.devices()), 8)
+    sess = Session(jax.devices()[:n])   # default policy=AUTO
 
-    print("=== 1. one submit path, both implementations (8 clusters) ===")
+    print(f"=== 1. one submit path, both implementations ({n} clusters) ===")
     for label, pol in (("baseline ", OffloadPolicy(
                             info_dist=InfoDist.P2P_CHAIN,
                             completion="central_counter")),
                        ("multicast", AUTO)):
         operands, expected = job.make_instance(0)
-        got = sess.submit(job, operands, n=8, policy=pol).wait()
-        colls = count_collectives(sess.runtime(pol).lowered_text(job, 8))
-        print(f"  {label}: allclose={np.allclose(got, expected)}  "
+        got = sess.submit(job, operands, n=n, policy=pol).wait()
+        colls = count_collectives(sess.runtime(pol).lowered_text(job, n))
+        print(f"  {label}: close={close(got, expected)}  "
               f"chain={colls['collective-permute']} collective-permutes, "
               f"{colls['all-reduce']} all-reduce")
 
     print("\n=== 2. AUTO: fused + pipelined + resident, planner-picked ===")
     instances, exps = jobs.make_instances(job, 16, seed0=2)
-    handle = sess.submit(job, instances, n=8)     # policy=AUTO
+    handle = sess.submit(job, instances, n=n)     # policy=AUTO
     results = handle.wait()
-    ok = all(np.allclose(r, e) for r, e in zip(results, exps))
+    ok = all(close(r, e) for r, e in zip(results, exps))
     d = handle.decision
     print(f"  16 jobs -> fuse={d.fuse}, window={d.window}, "
-          f"staging={d.staging.value}; allclose={ok}")
+          f"staging={d.staging.value}; close={ok}")
     print("  predicted vs measured (handle.explain()):")
     for line in str(handle.explain()).splitlines():
         print(f"    {line}")
-    sess.stage(job, instances[0], n=8)            # prime residency
-    got = sess.submit(job, Residency.RESIDENT, n=8).wait()
-    print(f"  resident redispatch: allclose={np.allclose(got, exps[0])}")
+    sess.stage(job, instances[0], n=n)            # prime residency
+    got = sess.submit(job, Residency.RESIDENT, n=n).wait()
+    print(f"  resident redispatch: close={close(got, exps[0])}")
 
     print("\n=== 3. cluster selection via the paper's address-mask (fig. 5) ===")
-    req = MulticastRequest(addr=1 << CLUSTER_OFFSET_BITS,
-                           mask=0b110 << CLUSTER_OFFSET_BITS)
+    # the odd clusters: cluster bit 0 set, the higher bits free
+    # (mask 0b110 on 8 clusters)
+    req = MulticastRequest(addr=int(n > 1) << CLUSTER_OFFSET_BITS,
+                           mask=((n - 1) & ~1) << CLUSTER_OFFSET_BITS)
     devs, ids = sess.runtime().select_clusters(request=req)
     operands, expected = job.make_instance(1)
     got = sess.submit(job, operands, request=req).wait()
-    print(f"  mask 0b110 over cluster bits -> clusters {ids}; "
-          f"allclose={np.allclose(got, expected)}")
+    print(f"  mask {bin((n - 1) & ~1)} over cluster bits -> clusters {ids}; "
+          f"close={close(got, expected)}")
 
     print("\n=== 4. the simulator: what this offload costs on Occamy ===")
     for n in (1, 4, 8, 32):

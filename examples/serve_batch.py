@@ -2,11 +2,11 @@
 different architecture families (dense GQA, MLA, hybrid SSM), driven by the
 ServeEngine with completion-unit tracking per step.
 
-    PYTHONPATH=src python examples/serve_batch.py
-"""
+It builds a 4x2 mesh, so it needs 8 devices; on the CPU:
 
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python examples/serve_batch.py
+"""
 
 import time
 

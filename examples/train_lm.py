@@ -2,14 +2,12 @@
 (reduced) SmolLM config on a 4×2 CPU mesh, with checkpointing, a simulated
 mid-run failure, and a bit-exact elastic resume on fewer devices.
 
-    PYTHONPATH=src python examples/train_lm.py [--steps 300]
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python examples/train_lm.py [--steps 300]
 
 (For the full-size architectures use repro.launch.train with --arch; the
 100M-scale end-to-end budget on CPU is covered by --reduced configs.)
 """
-
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse
 import tempfile

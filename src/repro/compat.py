@@ -1,44 +1,19 @@
-"""Version-compatibility shims for the JAX substrate.
+"""The one ``shard_map`` entry point every caller goes through.
 
-The framework targets the modern ``jax.shard_map`` entry point (with its
-``check_vma=`` argument); older installs (<= 0.4.x) only ship
-``jax.experimental.shard_map.shard_map`` whose equivalent knob is spelled
-``check_rep=``.  ``shard_map`` below resolves whichever is available once at
-import time so every caller (offload runtime, tests, benchmarks) goes through
-one code path.
+Wraps ``jax.shard_map`` so the offload runtime, tests and benchmarks
+spell its replication / varying-manual-axes checker the same way
+(``check=``, forwarded as ``check_vma=``).
 """
 
 from __future__ import annotations
-
-from typing import Any, Callable
 
 import jax
 
 __all__ = ["shard_map"]
 
 
-def _resolve() -> Callable[..., Any]:
-    new = getattr(jax, "shard_map", None)
-    if new is not None:
-        def via_new(f, *, mesh, in_specs, out_specs, check: bool = False):
-            return new(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       check_vma=check)
-        return via_new
-
-    from jax.experimental.shard_map import shard_map as old
-
-    def via_old(f, *, mesh, in_specs, out_specs, check: bool = False):
-        return old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check)
-    return via_old
-
-
-_impl = _resolve()
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """Map ``f`` over shards of a mesh; ``check`` toggles the replication /
-    varying-manual-axes checker (``check_vma`` on new JAX, ``check_rep`` on
-    the experimental fallback)."""
-    return _impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                 check=check)
+    """Map ``f`` over shards of a mesh; ``check`` toggles the
+    varying-manual-axes checker (``check_vma``)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -40,7 +40,9 @@ import numpy as np
 
 from repro.core.simulator import JobSpec
 
-DTYPE = jnp.float64  # the paper's workloads are double precision
+# The paper's workloads are double precision: operands are built as float64
+# host arrays, and each device computation keeps its operands' dtype — float64
+# under ``jax_enable_x64``, float32 without it (TPUs have no fp64 datapath).
 
 # Measured per-element execution coefficients (cycles per element per 8-core
 # cluster-group, §5.5 F and reconstructions for the remaining kernels).
@@ -141,7 +143,7 @@ def make_montecarlo(N: int = 16384) -> PaperJob:
 
     def compute(pts):
         hits = jnp.sum((pts**2).sum(axis=1) <= 1.0)
-        return 4.0 * hits.astype(DTYPE) / pts.shape[0] * 1.0
+        return 4.0 * hits.astype(pts.dtype) / pts.shape[0] * 1.0
 
     return PaperJob(
         spec=montecarlo_spec(N),
@@ -316,15 +318,16 @@ def make_bfs(V: int = 256, seed_graph: int = 0) -> PaperJob:
 
     def compute(adj_f):
         V_ = adj_f.shape[0]
-        dist0 = jnp.full((V_,), -1.0, dtype=DTYPE).at[0].set(0.0)
-        frontier0 = jnp.zeros((V_,), dtype=DTYPE).at[0].set(1.0)
+        dt = adj_f.dtype
+        dist0 = jnp.full((V_,), -1.0, dtype=dt).at[0].set(0.0)
+        frontier0 = jnp.zeros((V_,), dtype=dt).at[0].set(1.0)
 
         def body(state):
             dist, frontier, d = state
             reach = (adj_f.T @ frontier) > 0
             newly = reach & (dist < 0)
             dist = jnp.where(newly, d + 1.0, dist)
-            return dist, newly.astype(DTYPE), d + 1.0
+            return dist, newly.astype(dt), d + 1.0
 
         def cond(state):
             _, frontier, _ = state

@@ -1143,7 +1143,7 @@ class OffloadRuntime:
         mesh = Mesh(np.asarray(devices), (AXIS,))
         fn = self._build(job, mesh, n, tuple(sorted(operands)), (8,) if
                          fuse is None else (fuse, 8), fuse=fuse)
-        ftype = jnp.zeros((), jnp.float64).dtype  # honours jax_enable_x64
+        ftype = jax.dtypes.canonicalize_dtype(jnp.float64)  # x64 or f32
         lead = () if fuse is None else (fuse,)
         args_shape = lead + (8,)
         if self.config.info_dist == "p2p_chain":

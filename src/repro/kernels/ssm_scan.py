@@ -18,6 +18,11 @@ accumulator, never touching HBM.  Inside a chunk the recurrence runs as an
 fori_loop over time steps on VMEM-resident tiles (the TPU adaptation of the
 CUDA selective-scan kernel's shared-memory tiling; a log-depth associative
 formulation is a further hillclimb).
+
+Block sizes are fitted to scoped VMEM: a (…, block_d, N) tile pads N up to
+the 128-lane width, and the a and b inputs are each double-buffered, so the
+two input streams hold 4 · chunk · block_d · round_up(N, 128) elements.
+:func:`_fit_blocks` keeps that within :data:`VMEM_INPUT_BUDGET`.
 """
 
 from __future__ import annotations
@@ -30,6 +35,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import interpret_default, pad_to, round_up
+
+
+#: bytes of scoped VMEM the double-buffered a/b input blocks may take: half
+#: of the 16 MiB a TPU v5e kernel gets by default, leaving the rest for the
+#: c and y blocks and the (block_d, N) state scratch
+VMEM_INPUT_BUDGET = 8 << 20
+LANES = 128
+
+
+def _fit_blocks(D: int, N: int, chunk: int, block_d: int,
+                itemsize: int) -> tuple:
+    """-> (block_d, chunk) whose input blocks fit ``VMEM_INPUT_BUDGET``.
+
+    ``chunk`` shrinks (in steps of 8 sublanes) until a 128-channel block
+    fits; ``block_d`` then takes the largest multiple of 128 that fits, or
+    the whole (8-rounded) channel axis when that is smaller.
+    """
+    per_step_channel = 4 * round_up(N, LANES) * itemsize
+    chunk = max(8, min(chunk, VMEM_INPUT_BUDGET
+                       // (per_step_channel * LANES)) // 8 * 8)
+    fit = VMEM_INPUT_BUDGET // (per_step_channel * chunk) // LANES * LANES
+    return min(block_d, fit, round_up(D, 8)), chunk
 
 
 def _ssm_kernel(a_ref, b_ref, c_ref, y_ref, h_ref, *, chunk: int):
@@ -66,7 +93,7 @@ def ssm_scan(
     if interpret is None:
         interpret = interpret_default()
     B, S, D, N = a.shape
-    bd = min(block_d, round_up(D, 8))
+    bd, chunk = _fit_blocks(D, N, chunk, block_d, a.dtype.itemsize)
     Dp = round_up(D, bd)
     Sp = round_up(S, chunk)
     # pad decays with 1 and inputs with 0 so padded steps hold state

@@ -31,7 +31,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional, Tuple
 
-from repro.launch.roofline import HBM_BW, PEAK_FLOPS_BF16, Roofline
+from repro.launch.roofline import Roofline
 from repro.models.config import ModelConfig
 
 

@@ -1,12 +1,12 @@
 """Roofline analysis from compiled dry-run artifacts.
 
 Three terms per (architecture × shape × mesh) cell, all in seconds-per-step
-on the TARGET hardware (TPU v5e: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s
-ICI per link):
+on the TARGET hardware, whose peaks come from :data:`PEAKS` (the dry run
+targets ``TARGET_KIND``, a TPU v5e):
 
-    compute    = HLO_FLOPs_per_device   / 197e12
-    memory     = HLO_bytes_per_device   / 819e9
-    collective = collective_bytes_per_device / 50e9
+    compute    = HLO_FLOPs_per_device   / peak bf16 FLOP/s
+    memory     = HLO_bytes_per_device   / peak HBM B/s
+    collective = collective_bytes_per_device / peak ICI B/s per link
 
 Accounting notes (verified against a hand-checked matmul in
 tests/test_roofline.py):
@@ -28,10 +28,43 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-# --- TPU v5e hardware constants (per chip) ---------------------------------------
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW_PER_LINK = 50e9          # B/s
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops_bf16: float           # FLOP/s
+    hbm_bw: float               # B/s
+    ici_bw_per_link: float      # B/s
+    source: str
+
+
+#: peak table keyed by ``jax.Device.device_kind``; a kind that is not here
+#: has no peaks, and :func:`peaks` refuses it rather than guess
+PEAKS: Dict[str, ChipPeaks] = {
+    # 1,600 Gbit/s of chip-to-chip interconnect over 4 ICI links = 50 GB/s
+    # per link
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12, hbm_bw=819e9, ici_bw_per_link=50e9,
+        source='Google Cloud documentation, "TPU v5e" (per-chip '
+               'specifications)'),
+}
+
+#: the chip the dry-run roofline models
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; an unknown kind is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak table entry for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})") from None
+
+
+_TARGET = peaks(TARGET_KIND)
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -139,15 +172,15 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS_BF16
+        return self.flops / _TARGET.flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_accessed / HBM_BW
+        return self.bytes_accessed / _TARGET.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / ICI_BW_PER_LINK
+        return self.collective_bytes / _TARGET.ici_bw_per_link
 
     @property
     def bottleneck(self) -> str:
@@ -173,7 +206,7 @@ class Roofline:
         = (MODEL_FLOPS / chips / peak) / max-term."""
         if self.bound == 0:
             return 0.0
-        t_useful = self.model_flops / self.chips / PEAK_FLOPS_BF16
+        t_useful = self.model_flops / self.chips / _TARGET.flops_bf16
         return t_useful / self.bound
 
     def to_dict(self) -> Dict:

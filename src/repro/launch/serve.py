@@ -21,6 +21,7 @@ from repro.api import (
     FabricScheduler, ServeConfig, ServeEngine, ServeTenant, Staging,
 )
 from repro.data import DataConfig, SyntheticStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import get, init_params, reduced
 
@@ -77,6 +78,7 @@ def main() -> None:
                     help="mean arrivals per decode step of the Poisson-ish "
                          "trace (continuous mode)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get(args.arch)
     if args.reduced:

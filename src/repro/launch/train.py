@@ -26,6 +26,7 @@ from repro.core.completion import CompletionUnit
 from repro.data import DataConfig, SyntheticStream
 from repro.dist.sharding import to_shardings
 from repro.ft.straggler import StepWatchdog
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import get, init_params, reduced
 from repro.optim.adamw import adamw_init
@@ -49,6 +50,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get(args.arch)
     if args.reduced:
