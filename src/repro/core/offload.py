@@ -109,7 +109,6 @@ tests and ``benchmarks/offload_wallclock.py`` assert against.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
@@ -131,6 +130,7 @@ from repro.core.jobs import PaperJob, stack_instances
 from repro.core.policy import (
     Completion, InfoDist, Residency, Staging, coerce_enum, warn_legacy,
 )
+from repro.tracing import span
 
 AXIS = "clusters"
 
@@ -292,7 +292,6 @@ class JobHandle:
     result: Any                      # jax arrays (async until blocked on)
     arrivals: Any                    # device-side arrivals count
     n_clusters: int
-    dispatched_at: float
     runtime: "OffloadRuntime"
     cluster_ids: Tuple[int, ...] = ()
     plan: Optional["DispatchPlan"] = None
@@ -331,7 +330,8 @@ class JobHandle:
             raise self._fault
         if self._retired or self._done:
             return
-        arrivals = jax.device_get(self.arrivals)
+        with span("offload.fetch"):
+            arrivals = jax.device_get(self.arrivals)
         self._complete(int(arrivals))
         self.arrivals = None
 
@@ -363,10 +363,10 @@ class JobHandle:
         s = _san.active()
         if s is not None:
             s.read(self.result, f"wait() on job {self.job_id}")
-        if self._retired:
-            data = jax.device_get(self.result)
-        else:
-            data, arrivals = jax.device_get((self.result, self.arrivals))
+        with span("offload.fetch"):
+            data, arrivals = jax.device_get(
+                (self.result, None if self._retired else self.arrivals))
+        if arrivals is not None:
             self._complete(int(arrivals))
         if self.plan is not None:
             self.plan.stats.d2h_bytes += _nbytes_of(data)
@@ -520,45 +520,48 @@ class DispatchPlan:
         through device-to-device copies — ``stats.h2d_bytes`` grows by
         size, not n·size.
         """
-        via = self._resolve_via(via)
-        names = tuple(sorted(operands))
-        if names != tuple(name for name, _, _ in self.op_meta):
-            raise ValueError(
-                f"operand names {names} do not match plan {self.op_meta}")
-        staged = {}
-        donating = self.runtime.config.donate_operands
-        for name, shape, dtype in self.op_meta:
-            arr = np.asarray(_check_live(operands[name],
-                                         f"staged operand {name!r}"))
-            if tuple(arr.shape) != shape:
+        with span("offload.stage"):
+            via = self._resolve_via(via)
+            names = tuple(sorted(operands))
+            if names != tuple(name for name, _, _ in self.op_meta):
                 raise ValueError(
-                    f"operand {name} shape {arr.shape} != planned {shape}")
-            if str(arr.dtype) != dtype:
-                raise ValueError(
-                    f"operand {name} dtype {arr.dtype} != planned {dtype} "
-                    "(a dtype change needs a new plan, not a silent retrace)")
-            staged[name] = self._put(arr, self.op_shardings[name], via)
-            self.stats.device_puts += 1
+                    f"operand names {names} do not match plan {self.op_meta}")
+            staged = {}
+            donating = self.runtime.config.donate_operands
+            for name, shape, dtype in self.op_meta:
+                arr = np.asarray(_check_live(operands[name],
+                                             f"staged operand {name!r}"))
+                if tuple(arr.shape) != shape:
+                    raise ValueError(
+                        f"operand {name} shape {arr.shape} != planned {shape}")
+                if str(arr.dtype) != dtype:
+                    raise ValueError(
+                        f"operand {name} dtype {arr.dtype} != planned "
+                        f"{dtype} (a dtype change needs a new plan, not a "
+                        "silent retrace)")
+                staged[name] = self._put(arr, self.op_shardings[name], via)
+                self.stats.device_puts += 1
+                if slot is None:
+                    # donation restages from these refs later — snapshot
+                    # caller arrays so in-place mutation cannot skew the redo
+                    # (restages from our own snapshots skip the copy).  One
+                    # snapshot per operand at the tree root only: the
+                    # per-device fan-out copies live on the devices, never
+                    # on the host.
+                    self._resident_src[name] = (
+                        arr.copy() if donating and _caller_owned else arr)
             if slot is None:
-                # donation restages from these refs later — snapshot caller
-                # arrays so in-place mutation cannot skew the redo (restages
-                # from our own snapshots skip the copy).  One snapshot per
-                # operand at the tree root only: the per-device fan-out
-                # copies live on the devices, never on the host.
-                self._resident_src[name] = (
-                    arr.copy() if donating and _caller_owned else arr)
-        if slot is None:
-            self._resident = staged
-            self._staged_via = via
-        else:
-            # slot buffers are single-use: each stream submit stages fresh
-            # operands, so a donated dispatch consuming them needs no redo
-            self._slots[slot] = staged
-        s = _san.active()
-        if s is not None:
-            for name, buf in staged.items():
-                s.track(buf, f"staged operand {name!r}")
-        return staged
+                self._resident = staged
+                self._staged_via = via
+            else:
+                # slot buffers are single-use: each stream submit stages fresh
+                # operands, so a donated dispatch consuming them needs no redo
+                self._slots[slot] = staged
+            s = _san.active()
+            if s is not None:
+                for name, buf in staged.items():
+                    s.track(buf, f"staged operand {name!r}")
+            return staged
 
     def forward(self, name: str, value: Any, *,
                 rename: bool = False) -> Tuple[Any, int]:
@@ -632,33 +635,34 @@ class DispatchPlan:
         device-resident values take :meth:`forward`.  Returns
         ``(staged, forwarded_bytes_per_operand)``.
         """
-        via = self._resolve_via(via)
-        names = tuple(sorted(operands))
-        if names != tuple(name for name, _, _ in self.op_meta):
-            raise ValueError(
-                f"operand names {names} do not match plan {self.op_meta}")
-        staged: Dict[str, Any] = {}
-        fwd_bytes: Dict[str, int] = {}
-        for name, shape, dtype in self.op_meta:
-            value = operands[name]
-            if isinstance(value, jax.Array):
-                staged[name], fwd_bytes[name] = self.forward(name, value)
-            else:
-                arr = np.asarray(value)
-                if tuple(arr.shape) != shape:
-                    raise ValueError(
-                        f"operand {name} shape {arr.shape} != planned "
-                        f"{shape}")
-                if str(arr.dtype) != dtype:
-                    raise ValueError(
-                        f"operand {name} dtype {arr.dtype} != planned "
-                        f"{dtype}")
-                staged[name] = self._put(arr, self.op_shardings[name], via)
-                self.stats.device_puts += 1
-                s = _san.active()
-                if s is not None:
-                    s.track(staged[name], f"renamed operand {name!r}")
-        return staged, fwd_bytes
+        with span("offload.stage"):
+            via = self._resolve_via(via)
+            names = tuple(sorted(operands))
+            if names != tuple(name for name, _, _ in self.op_meta):
+                raise ValueError(
+                    f"operand names {names} do not match plan {self.op_meta}")
+            staged: Dict[str, Any] = {}
+            fwd_bytes: Dict[str, int] = {}
+            for name, shape, dtype in self.op_meta:
+                value = operands[name]
+                if isinstance(value, jax.Array):
+                    staged[name], fwd_bytes[name] = self.forward(name, value)
+                else:
+                    arr = np.asarray(value)
+                    if tuple(arr.shape) != shape:
+                        raise ValueError(
+                            f"operand {name} shape {arr.shape} != planned "
+                            f"{shape}")
+                    if str(arr.dtype) != dtype:
+                        raise ValueError(
+                            f"operand {name} dtype {arr.dtype} != planned "
+                            f"{dtype}")
+                    staged[name] = self._put(arr, self.op_shardings[name], via)
+                    self.stats.device_puts += 1
+                    s = _san.active()
+                    if s is not None:
+                        s.track(staged[name], f"renamed operand {name!r}")
+            return staged, fwd_bytes
 
     def invalidate(self, names: Optional[Sequence[str]] = None) -> None:
         """Drop resident operand buffers (all, or a named subset)."""
@@ -710,22 +714,23 @@ class DispatchPlan:
         link once.  Baseline (p2p_chain) args are materialized on cluster 0
         and tiled, an O(n)-byte host transfer by construction.
         """
-        if (self._args_dev is not None and self._args_val is not None
-                and np.array_equal(self._args_val, job_args)):
-            self.stats.args_hits += 1
+        with span("offload.stage"):
+            if (self._args_dev is not None and self._args_val is not None
+                    and np.array_equal(self._args_val, job_args)):
+                self.stats.args_hits += 1
+                return self._args_dev
+            if self.runtime.config.info_dist == "multicast":
+                host = job_args
+            else:
+                tiled = np.zeros((self.n_clusters,) + job_args.shape,
+                                 job_args.dtype)
+                tiled[0] = job_args
+                host = tiled
+            self._args_dev = self._put(np.asarray(host), self.args_sharding,
+                                       self._resolve_via(via))
+            self.stats.device_puts += 1
+            self._args_val = job_args.copy()
             return self._args_dev
-        if self.runtime.config.info_dist == "multicast":
-            host = job_args
-        else:
-            tiled = np.zeros((self.n_clusters,) + job_args.shape,
-                             job_args.dtype)
-            tiled[0] = job_args
-            host = tiled
-        self._args_dev = self._put(np.asarray(host), self.args_sharding,
-                                   self._resolve_via(via))
-        self.stats.device_puts += 1
-        self._args_val = job_args.copy()
-        return self._args_dev
 
     def _after_dispatch(self, consumed_resident: bool = True) -> None:
         self.stats.dispatches += 1
@@ -1008,8 +1013,8 @@ class OffloadRuntime:
                   else plan.stage(stacked, _caller_owned=False, via=staging))
         handle = self._launch(plan, args_dev, op_dev)
         return FusedHandle(handle.job_id, handle.result, handle.arrivals,
-                           plan.n_clusters, handle.dispatched_at, self,
-                           plan.cluster_ids, plan, batch=B)
+                           plan.n_clusters, self, plan.cluster_ids, plan,
+                           batch=B)
 
     def _launch(self, plan: DispatchPlan, args_dev: Any,
                 op_dev: Dict[str, Any],
@@ -1019,29 +1024,30 @@ class OffloadRuntime:
         return the in-flight handle."""
         job_id = self._job_counter
         self._job_counter += 1
-        self.unit.program(plan.n_clusters, job_id)
-        if self.fault_injector is not None:
-            # fault-injection hook: resolves this dispatch's scheduled
-            # effect (dropped arrivals / virtual delay) deterministically
-            self.fault_injector.on_dispatch(self, job_id, plan.cluster_ids,
-                                            plan.job.spec)
-        s = _san.active()
-        if s is not None:
-            # op_dev may alias plan._resident, which a donating
-            # _after_dispatch clears — snapshot the buffers first
-            op_bufs = [(name, op_dev[name]) for name, _, _ in plan.op_meta]
-            for name, buf in op_bufs:
-                s.read(buf, f"launch {job_id} operand {name!r}")
-        result, arrivals = plan.fn(
-            args_dev, *(op_dev[name] for name, _, _ in plan.op_meta))
-        plan._after_dispatch(consumed_resident=consumed_resident)
-        if s is not None:
-            if self.config.donate_operands:
+        with span("offload.launch", job=job_id):
+            self.unit.program(plan.n_clusters, job_id)
+            if self.fault_injector is not None:
+                # fault-injection hook: resolves this dispatch's scheduled
+                # effect (dropped arrivals / virtual delay) deterministically
+                self.fault_injector.on_dispatch(self, job_id, plan.cluster_ids,
+                                                plan.job.spec)
+            s = _san.active()
+            if s is not None:
+                # op_dev may alias plan._resident, which a donating
+                # _after_dispatch clears — snapshot the buffers first
+                op_bufs = [(name, op_dev[name]) for name, _, _ in plan.op_meta]
                 for name, buf in op_bufs:
-                    s.donate(buf, f"operand {name!r}")
-            s.track(result, f"job {job_id}'s result buffer")
-        return JobHandle(job_id, result, arrivals, plan.n_clusters,
-                         time.monotonic(), self, plan.cluster_ids, plan)
+                    s.read(buf, f"launch {job_id} operand {name!r}")
+            result, arrivals = plan.fn(
+                args_dev, *(op_dev[name] for name, _, _ in plan.op_meta))
+            plan._after_dispatch(consumed_resident=consumed_resident)
+            if s is not None:
+                if self.config.donate_operands:
+                    for name, buf in op_bufs:
+                        s.donate(buf, f"operand {name!r}")
+                s.track(result, f"job {job_id}'s result buffer")
+            return JobHandle(job_id, result, arrivals, plan.n_clusters, self,
+                             plan.cluster_ids, plan)
 
     def run(self, job: PaperJob, seed: int = 0, **sel) -> Tuple[Any, Any]:
         """Convenience: build an instance, offload it, return (got, expected)."""
@@ -1111,6 +1117,10 @@ class OffloadRuntime:
                 arrivals = central_counter_arrivals(done, AXIS, n)
             return out, arrivals
 
+        # the trace names the device operations by their program:
+        # jit_offload_axpy, jit_offload_axpy_fused
+        program.__name__ = "offload_" + job.spec.name.split("[")[0] + (
+            "" if fuse is None else "_fused")
         donate = tuple(range(1, 1 + len(op_names))) if cfg.donate_operands else ()
         fn = jax.jit(
             shard_map(

@@ -74,6 +74,7 @@ from repro.core.scoreboard import (
     resolve_graph,
 )
 from repro.core.stream import OffloadStream
+from repro.tracing import span
 
 #: dispatch-constant phases — paid once per launch, amortized by fusion
 CONST_PHASES = (Phase.A, Phase.B, Phase.C, Phase.D, Phase.H, Phase.I)
@@ -476,11 +477,12 @@ class SessionHandle:
         if self._done:
             return self._result
         out: List[Any] = []
-        for kind, h in self._parts:
-            if kind == "fused":
-                out.extend(h.wait_each())
-            else:
-                out.append(h.wait())
+        with span("offload.wait", job=self._parts[0][1].job_id):
+            for kind, h in self._parts:
+                if kind == "fused":
+                    out.extend(h.wait_each())
+                else:
+                    out.append(h.wait())
         self._wall = time.monotonic() - self._submitted_at
         self._result = out if self._multi else out[0]
         self._done = True
@@ -902,128 +904,135 @@ class Session:
         :attr:`Session.diagnostics`, on ``handle.findings``, and in
         ``handle.explain()``.
         """
-        self._check_open("submit")
-        pol = self.policy if policy is None else policy
-        if pol.retry is not None:
-            # reliable dispatch is synchronous: barrier every predecessor
-            for h in after:
-                for jh in self._job_handles_of(h):
-                    jh.retire()
-            return self._submit_reliable(job, operands, pol, job_args,
-                                         n, request, clusters)
-        resident = isinstance(operands, Residency)
-        if resident:
-            if operands is not Residency.RESIDENT:
-                raise ValueError(
-                    "pass an operand dict, a sequence of them, or "
-                    "Residency.RESIDENT")
-            # a resident submit stages nothing: drop any pinned staging
-            # along with the residency pin, so a policy whose staging
-            # primed the buffers (e.g. TREE via sess.stage) is reusable
-            # here instead of tripping the RESIDENT+staging contradiction
-            pol = pol.pinned(residency=Residency.RESIDENT, staging=None)
-        elif isinstance(operands, str):
-            raise TypeError(
-                "the session API takes typed operands: an operand dict, a "
-                "sequence of them, or Residency.RESIDENT (the legacy "
-                "'resident' string lives on offload() only)")
-        multi = (not resident
-                 and isinstance(operands, (list, tuple)))
-        if multi and not operands:
-            raise ValueError("empty instance list")
-        if not multi and not resident and not isinstance(operands, Mapping):
-            raise TypeError(f"unsupported operands {type(operands)!r}")
-        if self.verify and not resident:
-            self._verify_submit(job, operands, n, request, clusters)
+        with span("offload.submit", job_name=job.spec.name):
+            self._check_open("submit")
+            pol = self.policy if policy is None else policy
+            if pol.retry is not None:
+                # reliable dispatch is synchronous: barrier every predecessor
+                for h in after:
+                    for jh in self._job_handles_of(h):
+                        jh.retire()
+                return self._submit_reliable(job, operands, pol, job_args,
+                                             n, request, clusters)
+            resident = isinstance(operands, Residency)
+            if resident:
+                if operands is not Residency.RESIDENT:
+                    raise ValueError(
+                        "pass an operand dict, a sequence of them, or "
+                        "Residency.RESIDENT")
+                # a resident submit stages nothing: drop any pinned staging
+                # along with the residency pin, so a policy whose staging
+                # primed the buffers (e.g. TREE via sess.stage) is reusable
+                # here instead of tripping the RESIDENT+staging contradiction
+                pol = pol.pinned(residency=Residency.RESIDENT, staging=None)
+            elif isinstance(operands, str):
+                raise TypeError(
+                    "the session API takes typed operands: an operand dict, a "
+                    "sequence of them, or Residency.RESIDENT (the legacy "
+                    "'resident' string lives on offload() only)")
+            multi = (not resident
+                     and isinstance(operands, (list, tuple)))
+            if multi and not operands:
+                raise ValueError("empty instance list")
+            if (not multi and not resident
+                    and not isinstance(operands, Mapping)):
+                raise TypeError(f"unsupported operands {type(operands)!r}")
+            if self.verify and not resident:
+                with span("offload.verify"):
+                    self._verify_submit(job, operands, n, request, clusters)
 
-        ids, n = self._selection_ids(pol, n, request, clusters)
-        if after:
-            mine = set(ids)
-            for h in after:
-                for jh in self._job_handles_of(h):
-                    if not (set(jh.cluster_ids) & mine):
-                        jh.retire()   # disjoint: completion barrier
-        batch = (len(operands) if multi
-                 else (pol.fuse or 1) if resident else 1)
-        first_ops = (operands[0] if multi
-                     else None if resident else operands)
-        if resident:
-            entry = self._staged_residency.get((job.spec.name, tuple(ids)))
-            if entry is not None:
-                entry["uses"] += 1
-        cache_key = (job.spec.name, tuple(ids), batch, pol)
-        est = self._est_cache.get(cache_key)
-        if est is None:
-            est = estimate(job, clusters=ids, batch=batch, policy=pol,
-                           n_units=self.n_units, params=self.params,
-                           operands=first_ops, planner=self.planner)
-            self._est_cache[cache_key] = est
-        findings = self._lint_submit(
-            job, first_ops, pol, batch, ids,
-            self.lint if lint is None else lint, cache_key)
-        self._slo_gate(est, batch)
-        decision = est.decision
-        rt = self._runtime_for(pol)
-        t0 = time.monotonic()
-        parts: List[Tuple[str, Any]] = []
-        plans: List[Any] = []
+            with span("offload.plan"):
+                ids, n = self._selection_ids(pol, n, request, clusters)
+                if after:
+                    mine = set(ids)
+                    for h in after:
+                        for jh in self._job_handles_of(h):
+                            if not (set(jh.cluster_ids) & mine):
+                                jh.retire()   # disjoint: completion barrier
+                batch = (len(operands) if multi
+                         else (pol.fuse or 1) if resident else 1)
+                first_ops = (operands[0] if multi
+                             else None if resident else operands)
+                if resident:
+                    entry = self._staged_residency.get(
+                        (job.spec.name, tuple(ids)))
+                    if entry is not None:
+                        entry["uses"] += 1
+                cache_key = (job.spec.name, tuple(ids), batch, pol)
+                est = self._est_cache.get(cache_key)
+                if est is None:
+                    est = estimate(job, clusters=ids, batch=batch, policy=pol,
+                                   n_units=self.n_units, params=self.params,
+                                   operands=first_ops, planner=self.planner)
+                    self._est_cache[cache_key] = est
+                findings = self._lint_submit(
+                    job, first_ops, pol, batch, ids,
+                    self.lint if lint is None else lint, cache_key)
+                self._slo_gate(est, batch)
+                decision = est.decision
+                rt = self._runtime_for(pol)
+                stream = (None if multi or (resident and decision.fuse > 1)
+                          else self._stream_for(job, pol, decision, n, request,
+                                                clusters))
+            t0 = time.monotonic()
+            parts: List[Tuple[str, Any]] = []
+            plans: List[Any] = []
 
-        if resident and decision.fuse > 1:
-            h = rt._offload_fused(job, Residency.RESIDENT,
-                                  job_args=_one_args(job_args),
-                                  n=n, request=request, clusters=clusters,
-                                  batch=decision.fuse,
-                                  staging=decision.staging)
-            parts.append(("fused", h))
-            plans.append(self._last_fused_plan(rt, job, decision.fuse, ids))
-        elif not multi:
-            stream = self._stream_for(job, pol, decision, n, request,
-                                      clusters)
-            h = stream.submit(
-                Residency.RESIDENT if resident else operands,
-                _one_args(job_args))
-            parts.append(("single", h))
-            plans.append(stream.plan)
-        else:
-            B = decision.fuse
-            args_list = _args_list(job_args, batch)
-            i = 0
-            if B > 1:
-                # like OffloadStream, the in-flight window is capped by
-                # the runtime's completion-unit copies: launch k and
-                # launch k + n_units share a unit, so k must have
-                # completed first
-                window = min(decision.window, rt.unit.n_units)
-                while batch - i >= B:
-                    group = list(operands[i:i + B])
-                    gargs = _stack_args(args_list, i, B)
-                    while (len(self._fused_inflight) >= window
-                           and self._fused_inflight):
-                        self._fused_inflight.popleft().wait()
-                    h = rt._offload_fused(job, group, job_args=gargs,
-                                          n=n, request=request,
-                                          clusters=clusters,
-                                          staging=decision.staging)
-                    self._fused_inflight.append(h)
-                    parts.append(("fused", h))
-                    i += B
-                if parts:
-                    plans.append(self._last_fused_plan(rt, job,
-                                                       decision.fuse, ids))
-            if i < batch:
-                # remainder (or the unfused path): pipelined singles
-                stream = self._stream_for(job, pol, decision, n, request,
-                                          clusters)
-                for k in range(i, batch):
-                    h = stream.submit(
-                        operands[k],
-                        args_list[k] if args_list is not None else None)
-                    parts.append(("single", h))
+            if resident and decision.fuse > 1:
+                h = rt._offload_fused(job, Residency.RESIDENT,
+                                      job_args=_one_args(job_args),
+                                      n=n, request=request, clusters=clusters,
+                                      batch=decision.fuse,
+                                      staging=decision.staging)
+                parts.append(("fused", h))
+                plans.append(self._last_fused_plan(rt, job, decision.fuse,
+                                                   ids))
+            elif stream is not None:
+                h = stream.submit(
+                    Residency.RESIDENT if resident else operands,
+                    _one_args(job_args))
+                parts.append(("single", h))
                 plans.append(stream.plan)
+            else:
+                B = decision.fuse
+                args_list = _args_list(job_args, batch)
+                i = 0
+                if B > 1:
+                    # like OffloadStream, the in-flight window is capped by
+                    # the runtime's completion-unit copies: launch k and
+                    # launch k + n_units share a unit, so k must have
+                    # completed first
+                    window = min(decision.window, rt.unit.n_units)
+                    while batch - i >= B:
+                        group = list(operands[i:i + B])
+                        gargs = _stack_args(args_list, i, B)
+                        while (len(self._fused_inflight) >= window
+                               and self._fused_inflight):
+                            self._fused_inflight.popleft().wait()
+                        h = rt._offload_fused(job, group, job_args=gargs,
+                                              n=n, request=request,
+                                              clusters=clusters,
+                                              staging=decision.staging)
+                        self._fused_inflight.append(h)
+                        parts.append(("fused", h))
+                        i += B
+                    if parts:
+                        plans.append(self._last_fused_plan(rt, job,
+                                                           decision.fuse, ids))
+                if i < batch:
+                    # remainder (or the unfused path): pipelined singles
+                    stream = self._stream_for(job, pol, decision, n, request,
+                                              clusters)
+                    for k in range(i, batch):
+                        h = stream.submit(
+                            operands[k],
+                            args_list[k] if args_list is not None else None)
+                        parts.append(("single", h))
+                    plans.append(stream.plan)
 
-        return SessionHandle(self, job, est, parts, multi or
-                             (resident and decision.fuse > 1), plans, t0,
-                             findings=findings)
+            return SessionHandle(self, job, est, parts, multi or
+                                 (resident and decision.fuse > 1), plans, t0,
+                                 findings=findings)
 
     def _lint_submit(self, job: PaperJob, first_ops: Any,
                      pol: OffloadPolicy, batch: int, ids: Sequence[int],

@@ -64,6 +64,7 @@ from repro.models.model import (
     CallConfig, decode_step, decode_step_ragged, init_cache, init_params,
     prefill,
 )
+from repro.tracing import span
 
 Pytree = Any
 
@@ -99,11 +100,11 @@ def build_serve_step(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int,
     pspecs, cspecs, tok_sharding = (
         shardings or _serve_shardings(cfg, mesh, batch, max_len))
 
-    def step(params, cache, tokens):
+    def serve_step(params, cache, tokens):
         return decode_step(params, cfg, cache, tokens, call)
 
     jitted = jax.jit(
-        step,
+        serve_step,
         in_shardings=(
             to_shardings(pspecs, mesh),
             to_shardings(cspecs, mesh),
@@ -161,12 +162,12 @@ def build_sampling_step(cfg: ModelConfig, mesh: Mesh, batch: int,
     body = _decode_sample_body(cfg, temperature, call)
     repl = NamedSharding(mesh, P())
 
-    def step(params, cache, tok, key, idx):
+    def serve_sample_step(params, cache, tok, key, idx):
         nxt, key, cache = body(params, cache, tok, key, idx)
         return nxt[:, None], key, idx + 1, cache
 
     jitted = jax.jit(
-        step,
+        serve_sample_step,
         in_shardings=(
             to_shardings(pspecs, mesh), to_shardings(cspecs, mesh),
             tok_sharding, repl, repl,
@@ -195,7 +196,7 @@ def build_decode_chunk(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int,
     step_body = _decode_sample_body(cfg, temperature, call)
     repl = NamedSharding(mesh, P())
 
-    def chunk_fn(params, cache, tok, key, idx0):
+    def serve_decode_chunk(params, cache, tok, key, idx0):
         def body(carry, i):
             tok, key, cache = carry
             nxt, key, cache = step_body(params, cache, tok, key, i)
@@ -206,7 +207,7 @@ def build_decode_chunk(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int,
         return toks.T, tok, key, idx0 + chunk, cache    # toks: (B, chunk)
 
     jitted = jax.jit(
-        chunk_fn,
+        serve_decode_chunk,
         in_shardings=(
             to_shardings(pspecs, mesh), to_shardings(cspecs, mesh),
             tok_sharding, repl, repl,
@@ -236,7 +237,7 @@ def build_ragged_step(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int,
     sample = _sampler(temperature)
     repl = NamedSharding(mesh, P())
 
-    def step(params, cache, tok, pos_b, active, key, idx):
+    def serve_decode_step(params, cache, tok, pos_b, active, key, idx):
         logits, cache = decode_step_ragged(params, cfg, cache, tok, pos_b,
                                            call)
         lg = logits[:, 0] if logits.ndim == 3 else logits
@@ -246,7 +247,7 @@ def build_ragged_step(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int,
         return nxt[:, None], pos_b, key, idx + 1, cache
 
     jitted = jax.jit(
-        step,
+        serve_decode_step,
         in_shardings=(
             to_shardings(pspecs, mesh), to_shardings(cspecs, mesh),
             tok_sharding, repl, repl, repl, repl,
@@ -357,7 +358,7 @@ class ServeEngine:
         if self._insert_fn is None:
             cshard = to_shardings(self.cspecs, self.mesh)
 
-            def ins(cache, k_rows, v_rows, slot):
+            def serve_insert(cache, k_rows, v_rows, slot):
                 k = jax.lax.dynamic_update_slice(
                     cache["k"], k_rows.astype(cache["k"].dtype),
                     (0, slot, 0, 0))
@@ -366,16 +367,18 @@ class ServeEngine:
                     (0, slot, 0, 0))
                 return dict(cache, k=k, v=v)
 
-            self._insert_fn = jax.jit(ins, out_shardings=cshard,
+            self._insert_fn = jax.jit(serve_insert, out_shardings=cshard,
                                       donate_argnums=(0,))
         return self._insert_fn
 
     def _get_prefill_fn(self):
         # jit caches one program per prefill bucket length
         if self._prefill_fn is None:
-            self._prefill_fn = jax.jit(
-                lambda p, toks: prefill(p, self.cfg, {"tokens": toks},
-                                        self.scfg.max_len, self.call))
+            def serve_prefill(p, toks):
+                return prefill(p, self.cfg, {"tokens": toks},
+                               self.scfg.max_len, self.call)
+
+            self._prefill_fn = jax.jit(serve_prefill)
         return self._prefill_fn
 
     # -- hierarchical staging (weight placement + prefill inserts) ----------------
@@ -479,7 +482,11 @@ class ServeEngine:
         """Device-resident decode: the token never visits the host."""
         if self._first_fn is None:
             sample = _sampler(self.scfg.temperature)
-            self._first_fn = jax.jit(lambda lg, k: sample(lg, k)[:, None],
+
+            def serve_first_token(lg, k):
+                return sample(lg, k)[:, None]
+
+            self._first_fn = jax.jit(serve_first_token,
                                      out_shardings=self._tok_sharding)
         tok = self._first_fn(logits[:, -1], key)
         # the prefill-token sample is a real XLA launch emitting token 0
@@ -607,34 +614,38 @@ class ServeEngine:
             while queue and free:
                 r = queue.popleft()
                 j = free.pop(0)
-                cache, tok, pos_b, active = self._insert(
-                    cache, tok, pos_b, active, j, reqs[r][0])
+                with span("serve.admit", slot=j):
+                    cache, tok, pos_b, active = self._insert(
+                        cache, tok, pos_b, active, j, reqs[r][0])
                 slots[j] = {"req": r, "remaining": reqs[r][1]}
             if all(s is None for s in slots):
                 t = arrivals[order[pi]]     # batch idle: skip to next arrival
                 continue
             # one resident decode step advances every occupied slot
             job = self._dispatch_begin()
-            tok, pos_b, key, idx, cache = step_fn(
-                self.params, cache, tok, pos_b, active, key, idx)
+            with span("serve.decode"):
+                tok, pos_b, key, idx, cache = step_fn(
+                    self.params, cache, tok, pos_b, active, key, idx)
             live = [(j, s["req"]) for j, s in enumerate(slots)
                     if s is not None]
             self._dispatch_end(job, tokens=len(live))
             step_log.append((tok, live))
-            for j, s in enumerate(slots):
-                if s is None:
-                    continue
-                s["remaining"] -= 1
-                if s["remaining"] == 0:     # done-mask: retire the slot
-                    slots[j] = None
-                    free.append(j)
-                    free.sort()
-                    active = active.at[j].set(0)
-                    self.stats["requests_retired"] += 1
+            with span("serve.retire"):
+                for j, s in enumerate(slots):
+                    if s is None:
+                        continue
+                    s["remaining"] -= 1
+                    if s["remaining"] == 0:     # done-mask: retire the slot
+                        slots[j] = None
+                        free.append(j)
+                        free.sort()
+                        active = active.at[j].set(0)
+                        self.stats["requests_retired"] += 1
             t += 1
 
         # tokens stayed device-resident throughout; one drain at the end
-        fetched = jax.device_get([tk for tk, _ in step_log])
+        with span("serve.drain"):
+            fetched = jax.device_get([tk for tk, _ in step_log])
         results: List[List[int]] = [[] for _ in range(R)]
         for tk_host, (_, live) in zip(fetched, step_log):
             for j, r in live:
