@@ -18,6 +18,9 @@ from repro.kernels import ref
 from repro.kernels.atax import atax as _atax_pallas
 from repro.kernels.axpy import axpy as _axpy_pallas
 from repro.kernels.covariance import covariance as _cov_pallas
+from repro.kernels.decode_attention import (
+    decode_attention as _decode_pallas, fits_vmem as _decode_fits,
+)
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.matmul import matmul as _matmul_pallas
 
@@ -71,3 +74,18 @@ def attention(
     if _resolve(impl) == "pallas":
         return _flash_pallas(q, k, v, causal=causal, **kw)
     return ref.attention(q, k, v, causal=causal)
+
+
+def decode_attention(q, k, v, k_cache, v_cache, pos_b, layer, *,
+                     impl: str = "auto", **kw):
+    """One decode token per row against layer ``layer`` of the stacked
+    ``(L, B, Hkv*d, S)`` caches, writing the token's K/V at ``pos_b`` in
+    place (see ``kernels/decode_attention.py``).  A row's slab that does not
+    fit the kernel's VMEM budget takes the XLA path, and so does a program
+    that may span devices (more than one in view on a TPU): a Mosaic call
+    is not partitioned across a mesh."""
+    kernel = _resolve(impl) == "pallas" and (
+        impl == "pallas" or jax.device_count() == 1)
+    if kernel and _decode_fits(k_cache):
+        return _decode_pallas(q, k, v, k_cache, v_cache, pos_b, layer, **kw)
+    return ref.decode_attention(q, k, v, k_cache, v_cache, pos_b, layer)

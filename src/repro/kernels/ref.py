@@ -44,6 +44,41 @@ def attention(
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def decode_attention(q, k, v, k_cache, v_cache, pos_b, layer):
+    """The decode kernel's oracle (and its path off-TPU): the same
+    block-diagonal contraction of the stacked ``(L, B, Hkv*d, S)`` cache in
+    its stored dtype, with float32 probabilities in the output product."""
+    import jax
+
+    b, hq, d = q.shape
+    hkv = k.shape[1]
+    _, _, kvd, s_len = k_cache.shape
+    dt, f32 = k_cache.dtype, jnp.float32
+    eye = jnp.eye(hkv, dtype=dt)
+    q_blocks = jnp.einsum("bhge,hj->bhgje",
+                          q.reshape(b, hkv, hq // hkv, d).astype(dt),
+                          eye).reshape(b, hq, kvd)
+    kc = jax.lax.dynamic_index_in_dim(k_cache, layer, 0, keepdims=False)
+    vc = jax.lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False)
+    kn, vn = k.reshape(b, kvd).astype(dt), v.reshape(b, kvd).astype(dt)
+    scale = 1.0 / d ** 0.5
+    s = jnp.einsum("bqk,bks->bqs", q_blocks, kc,
+                   preferred_element_type=f32) * scale          # (B,Hq,S)
+    s = jnp.where(jnp.arange(s_len) < pos_b[:, None, None], s, -1e30)
+    s_new = jnp.einsum("bqk,bk->bq", q_blocks, kn,
+                       preferred_element_type=f32)[..., None] * scale
+    m = jnp.maximum(s.max(-1, keepdims=True), s_new)
+    p, p_new = jnp.exp(s - m), jnp.exp(s_new - m)
+    o = jnp.einsum("bqs,bks->bqk", p, vc, preferred_element_type=f32)
+    o = (o + p_new * vn.astype(f32)[:, None]) / (p.sum(-1, keepdims=True)
+                                                 + p_new)
+    o = jnp.einsum("bhgje,hj->bhge", o.reshape(b, hkv, hq // hkv, hkv, d),
+                   eye.astype(f32)).reshape(b, hq, d)
+    at, rows = jnp.minimum(pos_b, s_len - 1), jnp.arange(b)
+    return (o, k_cache.at[layer, rows, :, at].set(kn),
+            v_cache.at[layer, rows, :, at].set(vn))
+
+
 def ssm_scan(a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
     """Sequential oracle for the SSM scan kernel: h_t = a_t·h_{t-1} + b_t,
     y_t = Σ_n h_t[:, n]·c_t[n].  a, b: (B,S,D,N); c: (B,S,N) -> (B,S,D)."""

@@ -7,8 +7,10 @@ Three implementations behind one switch:
                 for 32k prefill (XLA lowers it on any backend);
   * "pallas"  — the flash-attention kernel (TPU; interpret-validated on CPU).
 
-Decode (single query token against a cache) is a separate, always-XLA path —
-it is a matvec, and its roofline is HBM-bound cache streaming.
+Decode (single query token against a cache) is a separate path — a matvec
+whose roofline is HBM-bound cache streaming: ``kops.decode_attention`` reads
+each layer's slab of the stacked cache once and writes the token in place
+(the Pallas kernel on TPU, its XLA oracle elsewhere).
 """
 
 from __future__ import annotations
@@ -190,62 +192,36 @@ def gqa_attention(x, p, cfg: ModelConfig, positions, *, impl="xla",
     return jnp.einsum("bsk,kd->bsd", _merge_heads(o), p["wo"].astype(x.dtype))
 
 
-def gqa_decode(x, p, cfg: ModelConfig, k_cache, v_cache, pos):
-    """One-token decode: update caches at `pos`, attend over cache[:pos+1].
-
-    k_cache/v_cache: (B, Smax, Hkv*dh).  Returns (out, k_cache, v_cache).
-    """
-    b = x.shape[0]
-    positions = jnp.full((b, 1), pos, jnp.int32)
-    q, k, v = gqa_project(x, p, cfg, positions)            # (B,H,1,d)
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, _merge_heads(k), (0, pos, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, _merge_heads(v), (0, pos, 0))
-    kk = _split_heads(k_cache, cfg.n_kv_heads)             # (B,Hkv,Smax,d)
-    vv = _split_heads(v_cache, cfg.n_kv_heads)
-    hq = cfg.n_heads
-    kk = jnp.repeat(kk, hq // cfg.n_kv_heads, axis=1)
-    vv = jnp.repeat(vv, hq // cfg.n_kv_heads, axis=1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), kk.astype(jnp.float32))
-    s = s / (cfg.head_dim ** 0.5)
-    valid = jnp.arange(k_cache.shape[1])[None, None, None, :] <= pos
-    s = jnp.where(valid, s, NEG_INF)
-    o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1),
-                   vv.astype(jnp.float32)).astype(x.dtype)
-    out = jnp.einsum("bsk,kd->bsd", _merge_heads(o), p["wo"].astype(x.dtype))
-    return out, k_cache, v_cache
-
-
-def gqa_decode_ragged(x, p, cfg: ModelConfig, k_cache, v_cache, pos_b):
+def gqa_decode_ragged(x, p, cfg: ModelConfig, k_cache, v_cache, pos_b,
+                      layer=0):
     """One-token decode with a *per-row* position (continuous batching).
 
-    ``pos_b``: (B,) int32 — row b's cache is updated at ``pos_b[b]`` and
-    attended over ``cache[b, :pos_b[b]+1]``, so slots whose requests joined
-    the batch at different times (different prompt lengths / arrival steps)
-    decode together in one program.  RoPE/positional encoding uses each
-    row's own absolute position.  k_cache/v_cache: (B, Smax, Hkv*dh).
+    ``pos_b``: (B,) int32 — row b attends over ``cache[layer, b, :,
+    :pos_b[b]]`` and this token, whose K/V is written in place at position
+    ``pos_b[b]``, so slots whose requests joined the batch at different
+    times (different prompt lengths / arrival steps) decode together in one
+    program.  RoPE/positional encoding uses each row's own absolute
+    position.  k_cache/v_cache: the stacked (L, B, Hkv*dh, Smax) caches,
+    positions minor; each layer's slab is read once, in its stored dtype
+    (``kops.decode_attention``).  Returns (out, k_cache, v_cache).
     """
-    positions = pos_b[:, None]                              # (B, 1)
-    q, k, v = gqa_project(x, p, cfg, positions)             # (B,H,1,d)
-    upd = jax.vmap(
-        lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0)))
-    k_cache = upd(k_cache, _merge_heads(k), pos_b)
-    v_cache = upd(v_cache, _merge_heads(v), pos_b)
-    kk = _split_heads(k_cache, cfg.n_kv_heads)              # (B,Hkv,Smax,d)
-    vv = _split_heads(v_cache, cfg.n_kv_heads)
-    hq = cfg.n_heads
-    kk = jnp.repeat(kk, hq // cfg.n_kv_heads, axis=1)
-    vv = jnp.repeat(vv, hq // cfg.n_kv_heads, axis=1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), kk.astype(jnp.float32))
-    s = s / (cfg.head_dim ** 0.5)
-    valid = (jnp.arange(k_cache.shape[1])[None, None, None, :]
-             <= pos_b[:, None, None, None])
-    s = jnp.where(valid, s, NEG_INF)
-    o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1),
-                   vv.astype(jnp.float32)).astype(x.dtype)
-    out = jnp.einsum("bsk,kd->bsd", _merge_heads(o), p["wo"].astype(x.dtype))
-    return out, k_cache, v_cache
+    b = x.shape[0]
+    q, k, v = gqa_project(x, p, cfg, pos_b[:, None])        # (B,H,1,d)
+    o, k_cache, v_cache = kops.decode_attention(
+        q[:, :, 0], k[:, :, 0], v[:, :, 0], k_cache, v_cache, pos_b, layer)
+    o = o.astype(x.dtype).reshape(b, 1, -1)
+    return (jnp.einsum("bsk,kd->bsd", o, p["wo"].astype(x.dtype)),
+            k_cache, v_cache)
+
+
+def gqa_decode(x, p, cfg: ModelConfig, k_cache, v_cache, pos, layer=0):
+    """One-token decode of every row at one position ``pos``: attend over
+    cache[layer, :, :, :pos] and this token, write it at ``pos``.
+
+    k_cache/v_cache: (L, B, Hkv*dh, Smax).  Returns (out, k_cache, v_cache).
+    """
+    pos_b = jnp.full((x.shape[0],), pos, jnp.int32)
+    return gqa_decode_ragged(x, p, cfg, k_cache, v_cache, pos_b, layer)
 
 
 # ---------------------------------------------------------------------------

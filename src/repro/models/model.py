@@ -369,8 +369,8 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
             "conv": jnp.zeros((L, batch_size, s.d_conv - 1,
                                cfg.d_inner + 2 * s.d_state), dt),
             "h": jnp.zeros((L, batch_size, H, s.headdim, s.d_state), jnp.float32),
-            "k": jnp.zeros((A, batch_size, max_len, kvd), dt),
-            "v": jnp.zeros((A, batch_size, max_len, kvd), dt),
+            "k": jnp.zeros((A, batch_size, kvd, max_len), dt),
+            "v": jnp.zeros((A, batch_size, kvd, max_len), dt),
             "pos": jnp.zeros((), jnp.int32),
         }
     if cfg.mla:
@@ -380,10 +380,13 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
             "krope": jnp.zeros((L, batch_size, max_len, m.qk_rope_head_dim), dt),
             "pos": jnp.zeros((), jnp.int32),
         }
+    # K/V caches are (L, B, Hkv*dh, Smax), positions minor: a TPU lays such
+    # an array out as written, so a decode step reads each layer's slab in
+    # place (kernels/decode_attention.py)
     kvd = cfg.n_kv_heads * cfg.head_dim
     return {
-        "k": jnp.zeros((L, batch_size, max_len, kvd), dt),
-        "v": jnp.zeros((L, batch_size, max_len, kvd), dt),
+        "k": jnp.zeros((L, batch_size, kvd, max_len), dt),
+        "v": jnp.zeros((L, batch_size, kvd, max_len), dt),
         "pos": jnp.zeros((), jnp.int32),
     }
 
@@ -435,18 +438,13 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Params,
 
             def apply_shared(args):
                 x, kc, vc = args
-                app = idx // period
-                k_app = jax.lax.dynamic_index_in_dim(kc, app, 0, keepdims=False)
-                v_app = jax.lax.dynamic_index_in_dim(vc, app, 0, keepdims=False)
                 hh = rms_norm(x, sb["ln1"], cfg.norm_eps)
-                o, k_app, v_app = attn.gqa_decode(
-                    hh, sb["attn"], shared_cfg, k_app, v_app, pos)
+                o, kc, vc = attn.gqa_decode(hh, sb["attn"], shared_cfg, kc, vc,
+                                            pos, idx // period)
                 x = x + o
                 hh = rms_norm(x, sb["ln2"], cfg.norm_eps)
                 x = x + gated_mlp(hh, sb["mlp"]["wi"], sb["mlp"]["wg"],
                                   sb["mlp"]["wo"], cfg.act)
-                kc = jax.lax.dynamic_update_index_in_dim(kc, k_app, app, 0)
-                vc = jax.lax.dynamic_update_index_in_dim(vc, v_app, app, 0)
                 return x, kc, vc
 
             x, kc, vc = jax.lax.cond(
@@ -479,25 +477,40 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Params,
         new_cache = {"c": c, "krope": kr, "pos": pos + 1}
 
     else:
-        def body(x, xs):
-            lp, kcl, vcl = xs
-            hin = rms_norm(x, lp["ln1"], cfg.norm_eps, plus_one=cfg.embed_scale)
-            o, kcl, vcl = attn.gqa_decode(hin, lp["attn"], cfg, kcl, vcl, pos)
-            x = x + o
-            hin = rms_norm(x, lp["ln2"], cfg.norm_eps, plus_one=cfg.embed_scale)
-            if cfg.moe:
-                delta, _ = moe_lib.moe_block(hin, lp["moe"], cfg, no_drop=True)
-                x = x + delta
-            else:
-                x = x + gated_mlp(hin, lp["mlp"]["wi"], lp["mlp"]["wg"],
-                                  lp["mlp"]["wo"], cfg.act)
-            return x, (kcl, vcl)
-
-        x, (kc, vc) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
+        x, kc, vc = _attn_decode_layers(params, cfg, cache, x,
+                                        jnp.full((b,), pos, jnp.int32))
         new_cache = {"k": kc, "v": vc, "pos": pos + 1}
 
     return unembed(params, cfg, x), new_cache
+
+
+def _attn_decode_layers(params: Params, cfg: ModelConfig, cache: Params,
+                        x: jnp.ndarray, pos_b: jnp.ndarray):
+    """The attention families' decode layer scan.  The stacked K/V caches
+    ride the carry and each layer writes only this token's column, in
+    place: no layer's slab is sliced out, copied or stacked into a new
+    cache.  -> (x, k_cache, v_cache)."""
+
+    def body(carry, xs):
+        x, kc, vc = carry
+        lp, idx = xs
+        hin = rms_norm(x, lp["ln1"], cfg.norm_eps, plus_one=cfg.embed_scale)
+        o, kc, vc = attn.gqa_decode_ragged(hin, lp["attn"], cfg, kc, vc,
+                                           pos_b, idx)
+        x = x + o
+        hin = rms_norm(x, lp["ln2"], cfg.norm_eps, plus_one=cfg.embed_scale)
+        if cfg.moe:
+            delta, _ = moe_lib.moe_block(hin, lp["moe"], cfg, no_drop=True)
+            x = x + delta
+        else:
+            x = x + gated_mlp(hin, lp["mlp"]["wi"], lp["mlp"]["wg"],
+                              lp["mlp"]["wo"], cfg.act)
+        return (x, kc, vc), None
+
+    (x, kc, vc), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cfg.n_layers)))
+    return x, kc, vc
 
 
 def decode_step_ragged(params: Params, cfg: ModelConfig, cache: Params,
@@ -524,23 +537,7 @@ def decode_step_ragged(params: Params, cfg: ModelConfig, cache: Params,
     if cfg.pos_embedding == "sinusoidal":
         x = x + sinusoidal_positions(pos_b[:, None], cfg.d_model).astype(dt)
 
-    def body(x, xs):
-        lp, kcl, vcl = xs
-        hin = rms_norm(x, lp["ln1"], cfg.norm_eps, plus_one=cfg.embed_scale)
-        o, kcl, vcl = attn.gqa_decode_ragged(hin, lp["attn"], cfg, kcl, vcl,
-                                             pos_b)
-        x = x + o
-        hin = rms_norm(x, lp["ln2"], cfg.norm_eps, plus_one=cfg.embed_scale)
-        if cfg.moe:
-            delta, _ = moe_lib.moe_block(hin, lp["moe"], cfg, no_drop=True)
-            x = x + delta
-        else:
-            x = x + gated_mlp(hin, lp["mlp"]["wi"], lp["mlp"]["wg"],
-                              lp["mlp"]["wo"], cfg.act)
-        return x, (kcl, vcl)
-
-    x, (kc, vc) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
+    x, kc, vc = _attn_decode_layers(params, cfg, cache, x, pos_b)
     new_cache = {"k": kc, "v": vc, "pos": jnp.max(pos_b) + 1}
     return unembed(params, cfg, x), new_cache
 
@@ -607,16 +604,10 @@ def prefill(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
                 x = x + gated_mlp(hh, sb["mlp"]["wi"], sb["mlp"]["wg"],
                                   sb["mlp"]["wo"], cfg.act)
                 app = idx // period
-                km = attn._merge_heads(k).astype(kc.dtype)
-                vm = attn._merge_heads(v).astype(vc.dtype)
-                kc = jax.lax.dynamic_update_slice(
-                    kc, jax.lax.dynamic_update_slice(
-                        jax.lax.dynamic_index_in_dim(kc, app, 0, keepdims=False),
-                        km, (0, 0, 0))[None], (app, 0, 0, 0))
-                vc = jax.lax.dynamic_update_slice(
-                    vc, jax.lax.dynamic_update_slice(
-                        jax.lax.dynamic_index_in_dim(vc, app, 0, keepdims=False),
-                        vm, (0, 0, 0))[None], (app, 0, 0, 0))
+                km = attn._merge_heads(k).swapaxes(1, 2).astype(kc.dtype)
+                vm = attn._merge_heads(v).swapaxes(1, 2).astype(vc.dtype)
+                kc = jax.lax.dynamic_update_slice(kc, km[None], (app, 0, 0, 0))
+                vc = jax.lax.dynamic_update_slice(vc, vm[None], (app, 0, 0, 0))
                 return x, kc, vc
 
             x, kc, vc = jax.lax.cond(
@@ -676,8 +667,10 @@ def prefill(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
             cache["krope"], stash[1].astype(cache["krope"].dtype), (0, 0, 0, 0))
     else:
         cache["k"] = jax.lax.dynamic_update_slice(
-            cache["k"], stash[0].astype(cache["k"].dtype), (0, 0, 0, 0))
+            cache["k"], stash[0].swapaxes(2, 3).astype(cache["k"].dtype),
+            (0, 0, 0, 0))
         cache["v"] = jax.lax.dynamic_update_slice(
-            cache["v"], stash[1].astype(cache["v"].dtype), (0, 0, 0, 0))
+            cache["v"], stash[1].swapaxes(2, 3).astype(cache["v"].dtype),
+            (0, 0, 0, 0))
     cache["pos"] = jnp.asarray(seq, jnp.int32)
     return unembed(params, cfg, x[:, -1:]), cache

@@ -113,6 +113,39 @@ def test_flash_blocks_sweep():
                                    rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("b,hq,hkv,d,s", [(4, 6, 2, 32, 256),
+                                          (4, 4, 4, 16, 64),
+                                          (4, 8, 1, 64, 200)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention(b, hq, hkv, d, s, dtype):
+    """Grouped-query decode against a stacked cache: the kernel's output
+    matches the oracle, and each row's token lands at its position (0, the
+    last cell, past the end -> the last cell, mid-way) of the given layer
+    and nowhere else."""
+    L, layer = 3, 1
+    q = jnp.asarray(RNG.standard_normal((b, hq, d)), dtype)
+    k = jnp.asarray(RNG.standard_normal((b, hkv, d)), dtype)
+    v = jnp.asarray(RNG.standard_normal((b, hkv, d)), dtype)
+    kc = jnp.asarray(RNG.standard_normal((L, b, hkv * d, s)), dtype)
+    vc = jnp.asarray(RNG.standard_normal((L, b, hkv * d, s)), dtype)
+    pos = jnp.asarray([0, s - 1, s, s // 3], jnp.int32)
+    got = ops.decode_attention(q, k, v, kc, vc, pos, jnp.int32(layer),
+                               impl="pallas")
+    want = ref.decode_attention(q, k, v, kc, vc, pos, jnp.int32(layer))
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               **_tol(dtype))
+    at = np.minimum(np.asarray(pos), s - 1)
+    for cache, new, row in ((kc, got[1], k), (vc, got[2], v)):
+        np.testing.assert_array_equal(np.asarray(new), np.asarray(
+            want[1] if cache is kc else want[2]))
+        hit = np.zeros(cache.shape, bool)
+        hit[layer, np.arange(b), :, at] = True
+        np.testing.assert_array_equal(np.asarray(new)[~hit],
+                                      np.asarray(cache)[~hit])
+        np.testing.assert_array_equal(np.asarray(new)[layer, np.arange(b), :, at],
+                                      np.asarray(row).reshape(b, -1))
+
+
 @given(n=st.integers(1, 2048))
 @settings(max_examples=20, deadline=None)
 def test_axpy_any_length(n):

@@ -13,9 +13,10 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import PartitionSpec, SingleDeviceSharding
 
 from repro.kernels import ops
+from repro.kernels.decode_attention import decode_attention
 from repro.kernels.ssm_scan import ssm_scan
 
 
@@ -54,6 +55,11 @@ CASES = {
     "flash_attention": (functools.partial(ops.attention, causal=True,
                                           impl="pallas", interpret=False),
                         [((1, 15, 2048, 64), BF16)] * 3),
+    "decode_attention": (
+        functools.partial(decode_attention, interpret=False),
+        [((64, 15, 64), BF16), ((64, 5, 64), BF16), ((64, 5, 64), BF16),
+         ((32, 64, 320, 2048), BF16), ((32, 64, 320, 2048), BF16),
+         ((64,), jnp.int32), ((), jnp.int32)]),
     "ssm_scan": (functools.partial(ssm_scan, interpret=False),
                  [((1, 256, 8192, 16), F32), ((1, 256, 8192, 16), F32),
                   ((1, 256, 16), F32)]),
@@ -66,3 +72,71 @@ def test_kernel_compiles_for_v5e(kernel, one_chip):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _compile_ragged_step(devices, b, s, monkeypatch):
+    """HLO text of the ragged serve step at smollm-360m's widths (2 layers,
+    a small vocabulary) for ``b`` slots of an ``s``-position cache, compiled
+    for the described chips ``devices`` as a (data, 1) mesh, the program
+    taking its TPU path as on the chip. -> (text, cfg)"""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from repro import models as M
+    from repro.serve.engine import build_ragged_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: len(devices))
+    cfg = dataclasses.replace(M.get("smollm-360m"), n_layers=2,
+                              vocab_size=4096)
+    mesh = Mesh(np.asarray(devices).reshape(-1, 1), ("data", "model"))
+    step, cspecs, tok = build_ragged_step(cfg, mesh, b, s, 0.0)
+    here = NamedSharding(mesh, PartitionSpec())
+    put = lambda tree, sh=here: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=here)
+    cache = jax.eval_shape(lambda: M.init_cache(cfg, b, s))
+    text = step.lower(
+        put(jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))),
+        jax.tree.map(lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+            cache, cspecs),
+        jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=tok),
+        i32(b), i32(b), put(jax.eval_shape(lambda: jax.random.key(0))), i32()
+    ).compile().as_text()
+    return text, cfg
+
+
+def test_serve_decode_step_reads_the_cache_in_place(one_chip, monkeypatch):
+    """The ragged serve step at smollm-360m's widths and its 64 x 2048 serve
+    cache, compiled for a v5e: the kernel is there, and no other operation
+    outputs an array of a layer's cache slab or larger, so no slab is
+    copied, relayout, repeated or upcast."""
+    import re
+
+    import numpy as np
+
+    b, s = 64, 2048
+    text, cfg = _compile_ragged_step(list(one_chip.device_set), b, s,
+                                     monkeypatch)
+    assert "tpu_custom_call" in text
+    slab = b * s * cfg.n_kv_heads * cfg.head_dim
+    passive = {"parameter", "custom-call", "tuple", "get-tuple-element",
+               "bitcast", "while", "call", "conditional", "opt-barrier"}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(", line)
+        if not m or m.group(2) in passive:
+            continue
+        sizes = [int(np.prod([int(x) for x in dims.split(",") if x]))
+                 for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1))]
+        assert max(sizes, default=0) < slab, line[:160]
+
+
+def test_serve_decode_step_compiles_across_chips(topo, monkeypatch):
+    """With the slots sharded over the four chips of a v5e 2x2 the step
+    compiles: a Mosaic call is not partitioned across a mesh, so with more
+    than one device in view the decode attention takes its XLA path."""
+    text, _ = _compile_ragged_step(list(topo.devices), 8, 256, monkeypatch)
+    assert "tpu_custom_call" not in text
