@@ -1,4 +1,4 @@
-"""moe 27L d2048 16H ff1408 v102400 MLA kvlora512 2shared+64routed top-6 [arXiv:2405.04434]
+"""moe 27L d2048 16H dense-ff10944 expert-ff1408 v102400 MLA kvlora512 YaRN 2shared+64routed top-6 [arXiv:2405.04434]
 
 Selectable via ``--arch deepseek-v2-lite-16b`` in repro.launch.{dryrun,train,serve}.
 The exact configuration lives in :mod:`repro.models.registry` (single source

@@ -20,6 +20,7 @@ from repro.kernels.axpy import axpy as _axpy_pallas
 from repro.kernels.covariance import covariance as _cov_pallas
 from repro.kernels.decode_attention import (
     decode_attention as _decode_pallas, fits_vmem as _decode_fits,
+    mla_decode_attention as _mla_decode_pallas,
 )
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.matmul import matmul as _matmul_pallas
@@ -84,8 +85,24 @@ def decode_attention(q, k, v, k_cache, v_cache, pos_b, layer, *,
     fit the kernel's VMEM budget takes the XLA path, and so does a program
     that may span devices (more than one in view on a TPU): a Mosaic call
     is not partitioned across a mesh."""
-    kernel = _resolve(impl) == "pallas" and (
-        impl == "pallas" or jax.device_count() == 1)
-    if kernel and _decode_fits(k_cache):
+    if _decode_kernel(impl) and _decode_fits(k_cache):
         return _decode_pallas(q, k, v, k_cache, v_cache, pos_b, layer, **kw)
     return ref.decode_attention(q, k, v, k_cache, v_cache, pos_b, layer)
+
+
+def mla_decode_attention(q, kv, cache, pos_b, layer, *, scale: float,
+                         v_dim: int, impl: str = "auto", **kw):
+    """MLA's absorbed decode against layer ``layer`` of the stacked latent
+    cache ``(L, B, r+ρ, S)``, writing this token's column at ``pos_b`` in
+    place: the decode kernel's latent mode, on the same terms as
+    ``decode_attention`` (one slab to fit, one device in view)."""
+    if _decode_kernel(impl) and _decode_fits(cache, slabs=1):
+        return _mla_decode_pallas(q, kv, cache, pos_b, layer, scale=scale,
+                                  v_dim=v_dim, **kw)
+    return ref.mla_decode_attention(q, kv, cache, pos_b, layer, scale=scale,
+                                    v_dim=v_dim)
+
+
+def _decode_kernel(impl: str) -> bool:
+    return _resolve(impl) == "pallas" and (
+        impl == "pallas" or jax.device_count() == 1)

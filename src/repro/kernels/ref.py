@@ -79,6 +79,33 @@ def decode_attention(q, k, v, k_cache, v_cache, pos_b, layer):
             v_cache.at[layer, rows, :, at].set(vn))
 
 
+def mla_decode_attention(q, kv, cache, pos_b, layer, *, scale, v_dim):
+    """The latent decode kernel's oracle (and its path off-TPU): each
+    head's absorbed query against layer ``layer`` of the stacked ``(L, B,
+    r+ρ, S)`` latent cache in its stored dtype, values its first ``v_dim``
+    rows, probabilities in the cache dtype in the output product."""
+    import jax
+
+    b = q.shape[0]
+    s_len = cache.shape[-1]
+    dt, f32 = cache.dtype, jnp.float32
+    c = jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+    qd, kn = q.astype(dt), kv.astype(dt)
+    s = jnp.einsum("bhk,bks->bhs", qd, c,
+                   preferred_element_type=f32) * scale          # (B,H,S)
+    s = jnp.where(jnp.arange(s_len) < pos_b[:, None, None], s, -1e30)
+    s_new = jnp.einsum("bhk,bk->bh", qd, kn,
+                       preferred_element_type=f32)[..., None] * scale
+    m = jnp.maximum(s.max(-1, keepdims=True), s_new)
+    p, p_new = jnp.exp(s - m), jnp.exp(s_new - m)
+    o = jnp.einsum("bhs,bvs->bhv", p.astype(dt), c[:, :v_dim],
+                   preferred_element_type=f32)
+    o = (o + p_new * kn[:, None, :v_dim].astype(f32)) / (
+        p.sum(-1, keepdims=True) + p_new)
+    at, rows = jnp.minimum(pos_b, s_len - 1), jnp.arange(b)
+    return o, cache.at[layer, rows, :, at].set(kn)
+
+
 def ssm_scan(a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
     """Sequential oracle for the SSM scan kernel: h_t = a_t·h_{t-1} + b_t,
     y_t = Σ_n h_t[:, n]·c_t[n].  a, b: (B,S,D,N); c: (B,S,N) -> (B,S,D)."""

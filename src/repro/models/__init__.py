@@ -3,7 +3,7 @@ and stub multimodal frontends — every assigned architecture family."""
 
 from repro.models.config import (
     FrontendConfig, HybridConfig, MLAConfig, MoEConfig, ModelConfig, SSMConfig,
-    reduced,
+    YarnConfig, reduced,
 )
 from repro.models.model import (
     CallConfig, decode_step, decode_step_ragged, forward, init_cache,
@@ -16,5 +16,5 @@ __all__ = [
     "MoEConfig", "ModelConfig", "SSMConfig", "count_params", "decode_step",
     "decode_step_ragged",
     "forward", "get", "init_cache", "init_params", "loss_fn", "prefill",
-    "reduced",
+    "YarnConfig", "reduced",
 ]

@@ -8,9 +8,10 @@ Three implementations behind one switch:
   * "pallas"  — the flash-attention kernel (TPU; interpret-validated on CPU).
 
 Decode (single query token against a cache) is a separate path — a matvec
-whose roofline is HBM-bound cache streaming: ``kops.decode_attention`` reads
-each layer's slab of the stacked cache once and writes the token in place
-(the Pallas kernel on TPU, its XLA oracle elsewhere).
+whose roofline is HBM-bound cache streaming: ``kops.decode_attention`` (GQA)
+and ``kops.mla_decode_attention`` (MLA's latent cache) read each layer's
+slab of the stacked cache once and write the token in place (the Pallas
+kernel on TPU, its XLA oracle elsewhere).
 """
 
 from __future__ import annotations
@@ -23,7 +24,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ops as kops
 from repro.models.config import ModelConfig
-from repro.models.layers import apply_rope
+from repro.models.layers import apply_rope, rms_norm, yarn_mscale
 
 NEG_INF = -1e30
 
@@ -49,10 +50,10 @@ def _mask(sq: int, skv: int, prefix_len: int = 0) -> jnp.ndarray:
     return allowed
 
 
-def _xla_attention(q, k, v, mask) -> jnp.ndarray:
+def _xla_attention(q, k, v, mask, scale=None) -> jnp.ndarray:
     d = q.shape[-1]
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
-    s = s / (d ** 0.5)
+    s = s / (d ** 0.5) if scale is None else s * scale
     s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
@@ -60,7 +61,7 @@ def _xla_attention(q, k, v, mask) -> jnp.ndarray:
 
 def _chunked_attention(q, k, v, *, prefix_len: int, chunk: int = 512,
                        remat_chunk: bool = False,
-                       q_sharding=None) -> jnp.ndarray:
+                       q_sharding=None, scale=None) -> jnp.ndarray:
     """Online-softmax over KV chunks (flash-attention in pure jnp/lax.scan).
 
     k and v may have different head dims (MLA: qk = nope+rope, v = v_dim).
@@ -86,7 +87,8 @@ def _chunked_attention(q, k, v, *, prefix_len: int, chunk: int = 512,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     kc = k.reshape(b, h, n_chunks, chunk, d).transpose(2, 0, 1, 3, 4)
     vc = v.reshape(b, h, n_chunks, chunk, dv).transpose(2, 0, 1, 3, 4)
-    q32 = q.astype(jnp.float32) / (d ** 0.5)
+    q32 = (q.astype(jnp.float32) / (d ** 0.5) if scale is None
+           else q.astype(jnp.float32) * scale)
     if q_sharding is not None:
         q32 = jax.lax.with_sharding_constraint(q32, q_sharding)
     rows = jnp.arange(sq)[:, None] + (skv - sq)
@@ -132,11 +134,16 @@ def multihead_attention(
     chunk: int = 512,
     remat_chunk: bool = False,
     q_sharding=None,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
+    """``scale``: the softmax scale, 1/sqrt(d) where None."""
     hq, hkv = q.shape[1], k.shape[1]
     if hq != hkv:  # GQA: repeat KV heads
         k = jnp.repeat(k, hq // hkv, axis=1)
         v = jnp.repeat(v, hq // hkv, axis=1)
+    if scale is not None and impl in ("pallas", "stub"):
+        # these take 1/sqrt(d): fold the rest of the scale into q
+        q = q * jnp.asarray(scale * q.shape[-1] ** 0.5, q.dtype)
     if impl == "pallas":
         if prefix_len:
             raise NotImplementedError("prefix-LM uses xla/chunked")
@@ -153,9 +160,9 @@ def multihead_attention(
     if impl == "chunked":
         return _chunked_attention(q, k, v, prefix_len=prefix_len, chunk=chunk,
                                   remat_chunk=remat_chunk,
-                                  q_sharding=q_sharding)
+                                  q_sharding=q_sharding, scale=scale)
     mask = _mask(q.shape[2], k.shape[2], prefix_len)
-    return _xla_attention(q, k, v, mask)
+    return _xla_attention(q, k, v, mask, scale)
 
 
 # ---------------------------------------------------------------------------
@@ -230,6 +237,16 @@ def gqa_decode(x, p, cfg: ModelConfig, k_cache, v_cache, pos, layer=0):
 # ---------------------------------------------------------------------------
 
 
+def mla_scale(cfg: ModelConfig) -> float:
+    """The softmax scale: 1/sqrt(nope + rope), times YaRN's mscale² where
+    ``rope_scaling`` sets ``mscale_all_dim`` (DeepSeek-V2)."""
+    m, y = cfg.mla, cfg.rope_scaling
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_project_q(x, p, cfg: ModelConfig, positions):
     m = cfg.mla
     dt = x.dtype
@@ -237,7 +254,8 @@ def mla_project_q(x, p, cfg: ModelConfig, positions):
     q = q.reshape(x.shape[0], x.shape[1], cfg.n_heads,
                   m.qk_nope_head_dim + m.qk_rope_head_dim).transpose(0, 2, 1, 3)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions[:, None], cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions[:, None], cfg.rope_theta,
+                        cfg.rope_scaling)
     return q_nope, q_rope
 
 
@@ -247,10 +265,10 @@ def mla_compress_kv(x, p, cfg: ModelConfig, positions):
     dt = x.dtype
     ckv = jnp.einsum("bsd,dk->bsk", x, p["wdkv"].astype(dt))
     c, k_rope = jnp.split(ckv, [m.kv_lora_rank], axis=-1)
-    from repro.models.layers import rms_norm
     c = rms_norm(c, p["kv_norm"], cfg.norm_eps)
     k_rope = apply_rope(k_rope[:, :, None, :].transpose(0, 2, 1, 3),
-                        positions[:, None], cfg.rope_theta)  # (B,1,S,rope)
+                        positions[:, None], cfg.rope_theta,
+                        cfg.rope_scaling)                  # (B,1,S,rope)
     return c, k_rope
 
 
@@ -273,38 +291,37 @@ def mla_attention(x, p, cfg: ModelConfig, positions, *, impl="xla",
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, k_rope_b], axis=-1)
     o = multihead_attention(q, k, v, impl=impl, chunk=chunk,
-                            remat_chunk=remat_chunk, q_sharding=q_sharding)
+                            remat_chunk=remat_chunk, q_sharding=q_sharding,
+                            scale=mla_scale(cfg))
     return jnp.einsum("bsk,kd->bsd", _merge_heads(o), p["wo"].astype(dt))
 
 
-def mla_decode(x, p, cfg: ModelConfig, c_cache, rope_cache, pos):
-    """One-token MLA decode against the compressed cache.
+def mla_decode_ragged(x, p, cfg: ModelConfig, cache, pos_b, layer=0):
+    """One-token MLA decode with a per-row position, absorbed, against the
+    stacked latent cache ``(L, B, rank + rope, S)``, positions minor.
 
-    c_cache: (B, Smax, rank); rope_cache: (B, Smax, rope_dim).
+    ``W_UK`` folds into the query, so each head scores the latent column
+    ``c ‖ k_rope`` directly: ``(q_nope·W_UKᵀ) ‖ q_rope``; the latent
+    output (the first ``rank`` rows of the column are the values) then
+    goes through ``W_UV`` and ``W_O``.  ``kops.mla_decode_attention`` reads
+    each row's slab once and writes this token's column at ``pos_b`` in
+    place.  Returns (out, cache).
     """
     m = cfg.mla
     dt = x.dtype
     b = x.shape[0]
-    positions = jnp.full((b, 1), pos, jnp.int32)
-    c_new, k_rope_new = mla_compress_kv(x, p, cfg, positions)
-    c_cache = jax.lax.dynamic_update_slice(c_cache, c_new, (0, pos, 0))
-    rope_cache = jax.lax.dynamic_update_slice(
-        rope_cache, k_rope_new[:, 0], (0, pos, 0))
-    q_nope, q_rope = mla_project_q(x, p, cfg, positions)   # (B,H,1,·)
-
-    # Absorb wuk into q (the MLA decode trick): score = (q_nope·wukᵀ)·c + q_rope·k_rope
-    wuk = p["wuk"].astype(dt).reshape(m.kv_lora_rank, cfg.n_heads, m.qk_nope_head_dim)
-    q_c = jnp.einsum("bhqn,rhn->bhqr", q_nope, wuk)        # (B,H,1,rank)
-    s = jnp.einsum("bhqr,bsr->bhqs", q_c.astype(jnp.float32),
-                   c_cache.astype(jnp.float32))
-    s = s + jnp.einsum("bhqn,bsn->bhqs", q_rope.astype(jnp.float32),
-                       rope_cache.astype(jnp.float32))
-    s = s / ((m.qk_nope_head_dim + m.qk_rope_head_dim) ** 0.5)
-    valid = jnp.arange(c_cache.shape[1])[None, None, None, :] <= pos
-    s = jnp.where(valid, s, NEG_INF)
-    pattn = jax.nn.softmax(s, -1)
-    o_c = jnp.einsum("bhqs,bsr->bhqr", pattn, c_cache.astype(jnp.float32))
-    wuv = p["wuv"].astype(dt).reshape(m.kv_lora_rank, cfg.n_heads, m.v_head_dim)
-    o = jnp.einsum("bhqr,rhn->bhqn", o_c.astype(dt), wuv)  # (B,H,1,v_dim)
-    out = jnp.einsum("bsk,kd->bsd", _merge_heads(o), p["wo"].astype(dt))
-    return out, c_cache, rope_cache
+    positions = pos_b[:, None]
+    c, k_rope = mla_compress_kv(x, p, cfg, positions)     # (B,1,r), (B,1,1,ρ)
+    q_nope, q_rope = mla_project_q(x, p, cfg, positions)  # (B,H,1,·)
+    wuk = p["wuk"].astype(dt).reshape(m.kv_lora_rank, cfg.n_heads,
+                                      m.qk_nope_head_dim)
+    q = jnp.concatenate([jnp.einsum("bhn,rhn->bhr", q_nope[:, :, 0], wuk),
+                         q_rope[:, :, 0]], axis=-1)      # (B,H,r+ρ)
+    col = jnp.concatenate([c[:, 0], k_rope[:, 0, 0]], axis=-1)
+    o, cache = kops.mla_decode_attention(q, col, cache, pos_b, layer,
+                                         scale=mla_scale(cfg),
+                                         v_dim=m.kv_lora_rank)
+    wuv = p["wuv"].astype(dt).reshape(m.kv_lora_rank, cfg.n_heads,
+                                      m.v_head_dim)
+    o = jnp.einsum("bhr,rhn->bhn", o.astype(dt), wuv).reshape(b, 1, -1)
+    return jnp.einsum("bsk,kd->bsd", o, p["wo"].astype(dt)), cache

@@ -21,6 +21,14 @@ class MoEConfig:
     n_shared: int = 0              # always-on shared experts
     router_noise: float = 0.0      # jitter for load balancing (train only)
     aux_loss_coef: float = 0.01    # load-balancing auxiliary loss
+    norm_topk_prob: bool = True    # renormalise the top-k gates to sum 1
+    #: (first, count): the routed experts this device holds, as one of
+    #: n_experts / count expert-parallel shares; None holds all of them
+    experts_held: Optional[Tuple[int, int]] = None
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,6 +37,18 @@ class MLAConfig:
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling as DeepSeek-V2 defines it (``rope_scaling``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,6 +94,7 @@ class ModelConfig:
     qkv_bias: bool = False
     tie_embeddings: bool = False
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None
     norm_eps: float = 1e-5
     pos_embedding: str = "rope"    # "rope" | "sinusoidal" (musicgen)
     act: str = "silu"              # "silu" (SwiGLU) | "gelu" (GeGLU, gemma)
@@ -84,6 +105,9 @@ class ModelConfig:
     ssm: Optional[SSMConfig] = None
     hybrid: Optional[HybridConfig] = None
     frontend: Optional[FrontendConfig] = None
+    #: leading layers with a dense MLP of width d_ff before the MoE layers
+    #: (DeepSeek's first_k_dense_replace); MoE configs only
+    first_k_dense: int = 0
     # numerics
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
@@ -95,6 +119,16 @@ class ModelConfig:
             raise ValueError(f"unknown family {self.family!r}")
         if self.n_heads and self.n_kv_heads and self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads must be a multiple of n_kv_heads (GQA)")
+        if self.first_k_dense and not (
+                self.moe and self.first_k_dense < self.n_layers):
+            raise ValueError("first_k_dense needs MoE layers after it")
+        if self.rope_scaling and not self.mla:
+            raise ValueError("rope_scaling (YaRN) is implemented for MLA")
+        held = self.moe.experts_held if self.moe else None
+        if held and not (held[1] >= 1 and 0 <= held[0]
+                         and held[0] + held[1] <= self.moe.n_experts):
+            raise ValueError(f"experts_held {held} outside "
+                             f"{self.moe.n_experts} experts")
 
     # ---- derived sizes -------------------------------------------------------
 
@@ -149,7 +183,7 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
     if cfg.moe:
         base["moe"] = dataclasses.replace(
             cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2), d_ff_expert=64,
-            n_shared=min(cfg.moe.n_shared, 1),
+            n_shared=min(cfg.moe.n_shared, 1), experts_held=None,
         )
     if cfg.mla:
         base["mla"] = MLAConfig(

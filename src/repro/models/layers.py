@@ -2,7 +2,8 @@
 
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,12 +34,45 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """NeoX-style half-rotation.  x: (..., S, D_head); positions: (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_range(head_dim: int, theta: float, yarn) -> Tuple[int, int]:
+    """(low, high): the frequency indices between which YaRN's ramp runs
+    from the original frequencies to the ``factor``-interpolated ones."""
+    def dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim(yarn.beta_slow)), head_dim - 1)
+    return low, high
+
+
+def yarn_freqs(head_dim: int, theta: float, yarn) -> jnp.ndarray:
+    """DeepSeek-V2's YaRN inverse frequencies (``DeepseekV2YarnRotary
+    Embedding``): extrapolated below ``low``, interpolated by ``factor``
+    above ``high``, a linear ramp between."""
+    extra = rope_freqs(head_dim, theta)
+    low, high = yarn_range(head_dim, theta, yarn)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / yarn.factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               yarn=None) -> jnp.ndarray:
+    """NeoX-style half-rotation.  x: (..., S, D_head); positions: (..., S).
+    ``yarn``: a ``YarnConfig``, for YaRN's frequencies and cos/sin scale."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                      # (d/2,)
+    freqs = rope_freqs(d, theta) if yarn is None else yarn_freqs(d, theta, yarn)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, d/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -103,12 +103,13 @@ def _moe_params(key, cfg: ModelConfig, L: int, dtype) -> Params:
     m = cfg.moe
     ks = jax.random.split(key, 5)
     d, e, fe = cfg.d_model, m.n_experts, m.d_ff_expert
+    eh = m.n_held                   # the router scores all e experts
     p = {
         "router": dense_init(ks[0], (L, d, e), -2, jnp.float32),
         "experts": {
-            "wi": dense_init(ks[1], (L, e, d, fe), -2, dtype),
-            "wg": dense_init(ks[2], (L, e, d, fe), -2, dtype),
-            "wo": dense_init(ks[3], (L, e, fe, d), -2, dtype),
+            "wi": dense_init(ks[1], (L, eh, d, fe), -2, dtype),
+            "wg": dense_init(ks[2], (L, eh, d, fe), -2, dtype),
+            "wo": dense_init(ks[3], (L, eh, fe, d), -2, dtype),
         },
     }
     if m.n_shared:
@@ -188,19 +189,69 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> Params:
                                 _mlp_params(keys[4], 1, cfg.d_model, cfg.d_ff, dtype)),
         }
     else:
-        layer["ln1"] = jnp.ones((L, cfg.d_model), dtype)
-        layer["ln2"] = jnp.ones((L, cfg.d_model), dtype)
-        if cfg.mla:
-            layer["attn"] = _mla_params(keys[2], cfg, L, dtype)
-        else:
-            layer["attn"] = _attn_params(
-                keys[2], cfg, L, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, dtype)
-        if cfg.moe:
-            layer["moe"] = _moe_params(keys[3], cfg, L, dtype)
-        else:
-            layer["mlp"] = _mlp_params(keys[3], L, cfg.d_model, cfg.d_ff, dtype)
+        k = cfg.first_k_dense
+        layer = _block_params(keys[2], keys[3], cfg, L - k, dtype,
+                              moe=cfg.moe is not None)
+        if k:
+            params["dense_layers"] = _block_params(keys[4], keys[5], cfg, k,
+                                                   dtype, moe=False)
     params["layers"] = layer
     return params
+
+
+def _block_params(k_attn, k_ffn, cfg: ModelConfig, L: int, dtype,
+                  moe: bool) -> Params:
+    """``L`` stacked attention-family layers: norms, attention, and an MoE
+    block or a dense MLP of width ``d_ff``."""
+    layer: Params = {"ln1": jnp.ones((L, cfg.d_model), dtype),
+                     "ln2": jnp.ones((L, cfg.d_model), dtype)}
+    if cfg.mla:
+        layer["attn"] = _mla_params(k_attn, cfg, L, dtype)
+    else:
+        layer["attn"] = _attn_params(
+            k_attn, cfg, L, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, dtype)
+    if moe:
+        layer["moe"] = _moe_params(k_ffn, cfg, L, dtype)
+    else:
+        layer["mlp"] = _mlp_params(k_ffn, L, cfg.d_model, cfg.d_ff, dtype)
+    return layer
+
+
+def _scan_stacks(body, carry, params: Params, cfg: ModelConfig):
+    """``lax.scan`` of ``body`` over each layer stack in turn, the leading
+    dense layers (``first_k_dense``) first, each layer given as (its
+    params, its index over both stacks: a cache indexes layers so).
+    -> (carry, [stacked outputs of each stack])."""
+    k = cfg.first_k_dense
+    stacks = ([(params["dense_layers"], 0)] if k else []) + [
+        (params["layers"], k)]
+    outs = []
+    for stack, first in stacks:
+        n = jax.tree.leaves(stack)[0].shape[0]
+        carry, ys = jax.lax.scan(body, carry, (stack, jnp.arange(first, first + n)))
+        outs.append(ys)
+    return carry, outs
+
+
+def _ffn(h, lp: Params, cfg: ModelConfig, no_drop: bool,
+         buffer_sharding=None):
+    """The layer's MoE block or dense MLP -> (delta, aux loss)."""
+    if "moe" in lp:
+        return moe_lib.moe_block(h, lp["moe"], cfg, no_drop=no_drop,
+                                 buffer_sharding=buffer_sharding)
+    return (gated_mlp(h, lp["mlp"]["wi"], lp["mlp"]["wg"], lp["mlp"]["wo"],
+                      cfg.act), jnp.float32(0.0))
+
+
+def _cast_layers(params: Params, cfg: ModelConfig) -> Params:
+    """One compute-dtype copy of every float32 layer weight."""
+    dt = jnp.dtype(cfg.compute_dtype)
+    cast = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: a.astype(dt) if a.dtype == jnp.float32 else a, tree)
+    out = dict(params, layers=cast(params["layers"]))
+    if "dense_layers" in params:
+        out["dense_layers"] = cast(params["dense_layers"])
+    return out
 
 
 # =============================================================================
@@ -270,12 +321,8 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
         # One compute-dtype weight copy per step, sharded like the originals:
         # the layer scan then gathers/reads 2-byte weights instead of 4-byte
         # (halves FSDP gather traffic + weight HBM reads; §Perf move M2).
-        dt = jnp.dtype(cfg.compute_dtype)
-        params = dict(params, layers=jax.tree.map(
-            lambda a: a.astype(dt) if a.dtype == jnp.float32 else a,
-            params["layers"]))
+        params = _cast_layers(params, cfg)
     x, positions, prefix_len = embed_inputs(params, cfg, batch)
-    L = cfg.n_layers
 
     def body(x, xs):
         lp, idx = xs
@@ -309,21 +356,16 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
                                            remat_chunk=call.attn_chunk_remat,
                                            q_sharding=call.attn_q_sharding)
             h = rms_norm(x, lp["ln2"], cfg.norm_eps, plus_one=cfg.embed_scale)
-            if cfg.moe:
-                delta, aux = moe_lib.moe_block(h, lp["moe"], cfg,
-                                               no_drop=call.moe_no_drop,
-                                               buffer_sharding=call.moe_buffer_sharding)
-                x = x + delta
-            else:
-                x = x + gated_mlp(h, lp["mlp"]["wi"], lp["mlp"]["wg"],
-                                  lp["mlp"]["wo"], cfg.act)
+            delta, aux = _ffn(h, lp, cfg, call.moe_no_drop,
+                              call.moe_buffer_sharding)
+            x = x + delta
         return _constrain(x, call), aux
 
     if call.remat:
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.nothing_saveable)
-    x, aux = jax.lax.scan(body, x, (params["layers"], jnp.arange(L)))
-    return unembed(params, cfg, x), jnp.sum(aux)
+    x, aux = _scan_stacks(body, x, params, cfg)
+    return unembed(params, cfg, x), sum(jnp.sum(a) for a in aux)
 
 
 def loss_fn(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
@@ -373,16 +415,13 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
             "v": jnp.zeros((A, batch_size, kvd, max_len), dt),
             "pos": jnp.zeros((), jnp.int32),
         }
-    if cfg.mla:
-        m = cfg.mla
-        return {
-            "c": jnp.zeros((L, batch_size, max_len, m.kv_lora_rank), dt),
-            "krope": jnp.zeros((L, batch_size, max_len, m.qk_rope_head_dim), dt),
-            "pos": jnp.zeros((), jnp.int32),
-        }
-    # K/V caches are (L, B, Hkv*dh, Smax), positions minor: a TPU lays such
+    # K/V caches are (L, B, Hkv*dh, Smax), and MLA's latent cache (c_kv ‖
+    # k_rope) is (L, B, rank + rope, Smax), positions minor: a TPU lays such
     # an array out as written, so a decode step reads each layer's slab in
     # place (kernels/decode_attention.py)
+    if cfg.mla:
+        return {"latent": jnp.zeros((L, batch_size, cfg.kv_dim, max_len), dt),
+                "pos": jnp.zeros((), jnp.int32)}
     kvd = cfg.n_kv_heads * cfg.head_dim
     return {
         "k": jnp.zeros((L, batch_size, kvd, max_len), dt),
@@ -457,60 +496,41 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Params,
              jnp.arange(cfg.n_layers)))
         new_cache = {"conv": conv, "h": h, "k": kc, "v": vc, "pos": pos + 1}
 
-    elif cfg.mla:
-        def body(x, xs):
-            lp, c, kr = xs
-            hin = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            o, c, kr = attn.mla_decode(hin, lp["attn"], cfg, c, kr, pos)
-            x = x + o
-            hin = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            if cfg.moe:
-                delta, _ = moe_lib.moe_block(hin, lp["moe"], cfg, no_drop=True)
-                x = x + delta
-            else:
-                x = x + gated_mlp(hin, lp["mlp"]["wi"], lp["mlp"]["wg"],
-                                  lp["mlp"]["wo"], cfg.act)
-            return x, (c, kr)
-
-        x, (c, kr) = jax.lax.scan(
-            body, x, (params["layers"], cache["c"], cache["krope"]))
-        new_cache = {"c": c, "krope": kr, "pos": pos + 1}
-
     else:
-        x, kc, vc = _attn_decode_layers(params, cfg, cache, x,
-                                        jnp.full((b,), pos, jnp.int32))
-        new_cache = {"k": kc, "v": vc, "pos": pos + 1}
+        x, new_cache = _attn_decode_layers(params, cfg, cache, x,
+                                           jnp.full((b,), pos, jnp.int32))
+        new_cache["pos"] = pos + 1
 
     return unembed(params, cfg, x), new_cache
 
 
 def _attn_decode_layers(params: Params, cfg: ModelConfig, cache: Params,
                         x: jnp.ndarray, pos_b: jnp.ndarray):
-    """The attention families' decode layer scan.  The stacked K/V caches
-    ride the carry and each layer writes only this token's column, in
-    place: no layer's slab is sliced out, copied or stacked into a new
-    cache.  -> (x, k_cache, v_cache)."""
+    """The attention families' decode layer scans.  The stacked caches (K/V,
+    or MLA's latent cache) ride the carry and each layer writes only this
+    token's column, in place: no layer's slab is sliced out, copied or
+    stacked into a new cache.  -> (x, caches without ``pos``)."""
+    names = ("latent",) if cfg.mla else ("k", "v")
 
     def body(carry, xs):
-        x, kc, vc = carry
+        x, kv = carry
         lp, idx = xs
         hin = rms_norm(x, lp["ln1"], cfg.norm_eps, plus_one=cfg.embed_scale)
-        o, kc, vc = attn.gqa_decode_ragged(hin, lp["attn"], cfg, kc, vc,
-                                           pos_b, idx)
+        if cfg.mla:
+            o, lat = attn.mla_decode_ragged(hin, lp["attn"], cfg, kv[0],
+                                            pos_b, idx)
+            kv = (lat,)
+        else:
+            o, kc, vc = attn.gqa_decode_ragged(hin, lp["attn"], cfg, *kv,
+                                               pos_b, idx)
+            kv = (kc, vc)
         x = x + o
         hin = rms_norm(x, lp["ln2"], cfg.norm_eps, plus_one=cfg.embed_scale)
-        if cfg.moe:
-            delta, _ = moe_lib.moe_block(hin, lp["moe"], cfg, no_drop=True)
-            x = x + delta
-        else:
-            x = x + gated_mlp(hin, lp["mlp"]["wi"], lp["mlp"]["wg"],
-                              lp["mlp"]["wo"], cfg.act)
-        return (x, kc, vc), None
+        return (x + _ffn(hin, lp, cfg, no_drop=True)[0], kv), None
 
-    (x, kc, vc), _ = jax.lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(cfg.n_layers)))
-    return x, kc, vc
+    (x, kv), _ = _scan_stacks(body, (x, tuple(cache[n] for n in names)),
+                              params, cfg)
+    return x, dict(zip(names, kv))
 
 
 def decode_step_ragged(params: Params, cfg: ModelConfig, cache: Params,
@@ -522,14 +542,14 @@ def decode_step_ragged(params: Params, cfg: ModelConfig, cache: Params,
     ``pos_b``: (B,) int32 — each batch row writes its KV at its own cache
     position and attends over its own prefix, so rows at different
     generation depths (late-joining requests, different prompt lengths)
-    share one program.  Attention families only: SSM/hybrid state caches
-    are position-free recurrences whose shared scan carry cannot be
-    row-shifted, and MLA keeps the uniform-``pos`` path for now.
+    share one program.  Attention families (GQA and MLA) only: SSM/hybrid
+    state caches are position-free recurrences whose shared scan carry
+    cannot be row-shifted.
     """
-    if cfg.family in ("ssm", "hybrid") or cfg.mla or cfg.frontend:
+    if cfg.family in ("ssm", "hybrid") or cfg.frontend:
         raise NotImplementedError(
-            "ragged decode is implemented for the plain attention family "
-            "only (no SSM/hybrid/MLA state, no modality-prefix frontends)")
+            "ragged decode is implemented for the attention families only "
+            "(no SSM/hybrid state, no modality-prefix frontends)")
     dt = jnp.dtype(cfg.compute_dtype)
     x = params["embed"][tokens].astype(dt)
     if cfg.embed_scale:
@@ -537,8 +557,8 @@ def decode_step_ragged(params: Params, cfg: ModelConfig, cache: Params,
     if cfg.pos_embedding == "sinusoidal":
         x = x + sinusoidal_positions(pos_b[:, None], cfg.d_model).astype(dt)
 
-    x, kc, vc = _attn_decode_layers(params, cfg, cache, x, pos_b)
-    new_cache = {"k": kc, "v": vc, "pos": jnp.max(pos_b) + 1}
+    x, new_cache = _attn_decode_layers(params, cfg, cache, x, pos_b)
+    new_cache["pos"] = jnp.max(pos_b) + 1
     return unembed(params, cfg, x), new_cache
 
 
@@ -552,10 +572,7 @@ def prefill(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
             ) -> Tuple[jnp.ndarray, Params]:
     """Process a full prompt, returning (last-position logits, primed cache)."""
     if call.cast_params_once:
-        dtc = jnp.dtype(cfg.compute_dtype)
-        params = dict(params, layers=jax.tree.map(
-            lambda a: a.astype(dtc) if a.dtype == jnp.float32 else a,
-            params["layers"]))
+        params = _cast_layers(params, cfg)
     x, positions, prefix_len = embed_inputs(params, cfg, batch)
     b, s = x.shape[0], x.shape[1]
     dt = jnp.dtype(cfg.compute_dtype)
@@ -633,7 +650,7 @@ def prefill(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
                                        chunk=call.attn_chunk,
                                        remat_chunk=call.attn_chunk_remat,
                                        q_sharding=call.attn_q_sharding)
-            stash = (c, krope[:, 0])
+            stash = jnp.concatenate([c, krope[:, 0]], axis=-1)
         else:
             q, k, v = attn.gqa_project(h, lp["attn"], cfg, positions)
             o = attn.multihead_attention(q, k, v, impl=call.attn_impl,
@@ -645,26 +662,20 @@ def prefill(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
                                lp["attn"]["wo"].astype(dt))
             stash = (attn._merge_heads(k), attn._merge_heads(v))
         h = rms_norm(x, lp["ln2"], cfg.norm_eps, plus_one=cfg.embed_scale)
-        if cfg.moe:
-            delta, _ = moe_lib.moe_block(h, lp["moe"], cfg,
-                                         no_drop=call.moe_no_drop)
-            x = x + delta
-        else:
-            x = x + gated_mlp(h, lp["mlp"]["wi"], lp["mlp"]["wg"],
-                              lp["mlp"]["wo"], cfg.act)
-        return x, stash
+        return x + _ffn(h, lp, cfg, call.moe_no_drop)[0], stash
 
     if call.remat:
         body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
-    x, stash = jax.lax.scan(body, x, (params["layers"], jnp.arange(cfg.n_layers)))
+    x, stashes = _scan_stacks(body, x, params, cfg)
+    stash = stashes[0] if len(stashes) == 1 else jax.tree.map(
+        lambda *a: jnp.concatenate(a), *stashes)
 
     cache = init_cache(cfg, b, max_len)
     seq = x.shape[1]
     if cfg.mla:
-        cache["c"] = jax.lax.dynamic_update_slice(
-            cache["c"], stash[0].astype(cache["c"].dtype), (0, 0, 0, 0))
-        cache["krope"] = jax.lax.dynamic_update_slice(
-            cache["krope"], stash[1].astype(cache["krope"].dtype), (0, 0, 0, 0))
+        cache["latent"] = jax.lax.dynamic_update_slice(
+            cache["latent"], stash.swapaxes(2, 3).astype(cache["latent"].dtype),
+            (0, 0, 0, 0))
     else:
         cache["k"] = jax.lax.dynamic_update_slice(
             cache["k"], stash[0].swapaxes(2, 3).astype(cache["k"].dtype),

@@ -43,11 +43,18 @@ def moe_block(
     no_drop: bool = False,          # exact routing (serving / eval)
     buffer_sharding=None,           # EP constraint on the (E, C, D) buffer
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (output, aux_load_balance_loss)."""
+    """Returns (output, aux_load_balance_loss).
+
+    The router scores all ``n_experts``; the experts computed are the ones
+    held (``MoEConfig.experts_held``): a (token, expert) pair routed to an
+    expert held elsewhere contributes nothing here and takes no capacity.
+    """
     m = cfg.moe
     b, s, d = x.shape
     t = b * s
     e, k = m.n_experts, m.top_k
+    first = m.experts_held[0] if m.experts_held else 0
+    eh = m.n_held
     c = capacity(t, e, k, no_drop)
     xf = x.reshape(t, d)
 
@@ -56,8 +63,9 @@ def moe_block(
                         p["router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)                    # (T, E)
     gate_vals, gate_idx = jax.lax.top_k(probs, k)              # (T, K)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)                # renormalize
+    if m.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
 
     # --- load-balancing aux loss (Switch-style) --------------------------------
     me = probs.mean(axis=0)                                    # (E,)
@@ -65,16 +73,19 @@ def moe_block(
     ce = one_hot_top1.mean(axis=0)
     aux = m.aux_loss_coef * e * jnp.sum(me * ce)
 
-    # --- dispatch: positions within each expert's capacity ----------------------
-    # flat (T*K,) expert choices, priority by (k, token) order
-    e_flat = gate_idx.T.reshape(-1)                            # (K*T,)
-    onehot = jax.nn.one_hot(e_flat, e, dtype=jnp.int32)        # (K*T, E)
-    pos_in_e = jnp.cumsum(onehot, axis=0) - onehot             # (K*T, E)
+    # --- dispatch: positions within each held expert's capacity -----------------
+    # flat (T*K,) expert choices, priority by (k, token) order; an expert
+    # held elsewhere has an all-zero one-hot row
+    e_flat = gate_idx.T.reshape(-1) - first                    # (K*T,)
+    held = (e_flat >= 0) & (e_flat < eh)
+    onehot = jax.nn.one_hot(e_flat, eh, dtype=jnp.int32)       # (K*T, Eh)
+    pos_in_e = jnp.cumsum(onehot, axis=0) - onehot             # (K*T, Eh)
+    e_flat = jnp.clip(e_flat, 0, eh - 1)
     pos_flat = jnp.take_along_axis(pos_in_e, e_flat[:, None], axis=1)[:, 0]
-    keep = pos_flat < c
+    keep = held & (pos_flat < c)
     pos_clamped = jnp.minimum(pos_flat, c - 1)
 
-    buf = jnp.zeros((e, c, d), x.dtype)
+    buf = jnp.zeros((eh, c, d), x.dtype)
     tok_idx = jnp.tile(jnp.arange(t), k)
     contrib = jnp.where(keep[:, None], xf[tok_idx], 0.0)
     buf = buf.at[e_flat, pos_clamped].add(contrib)             # (E, C, D)

@@ -2,7 +2,8 @@
 
 Sources are the public configs cited in the assignment; where the assignment
 line and the upstream checkpoint disagree, the assignment line wins and the
-deviation is noted in DESIGN.md §Arch-assumptions.
+deviation is noted in DESIGN.md §Arch-assumptions.  deepseek-v2-lite-16b
+follows its published config.json.
 """
 
 from __future__ import annotations
@@ -19,6 +20,7 @@ from repro.models.config import (
     MoEConfig,
     ModelConfig,
     SSMConfig,
+    YarnConfig,
 )
 
 ARCHS: Dict[str, ModelConfig] = {}
@@ -60,13 +62,21 @@ LLAMA4_SCOUT = _register(ModelConfig(
     moe=MoEConfig(n_experts=16, top_k=1, d_ff_expert=8192, n_shared=1),
 ))
 
+# deepseek-ai/DeepSeek-V2-Lite config.json: layer 0 is a dense SwiGLU of
+# intermediate_size (first_k_dense_replace 1), raw softmax gates
+# (norm_topk_prob false), YaRN rope_scaling, rms_norm_eps 1e-6
 DEEPSEEK_V2_LITE = _register(ModelConfig(
     name="deepseek-v2-lite-16b", family="moe",
     n_layers=27, d_model=2048, n_heads=16, n_kv_heads=16,
-    d_ff=1408, vocab_size=102400,
+    d_ff=10944, vocab_size=102400, norm_eps=1e-6, first_k_dense=1,
+    rope_scaling=YarnConfig(factor=40.0,
+                            original_max_position_embeddings=4096,
+                            beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                            mscale_all_dim=0.707),
     mla=MLAConfig(kv_lora_rank=512, qk_nope_head_dim=128,
                   qk_rope_head_dim=64, v_head_dim=128),
-    moe=MoEConfig(n_experts=64, top_k=6, d_ff_expert=1408, n_shared=2),
+    moe=MoEConfig(n_experts=64, top_k=6, d_ff_expert=1408, n_shared=2,
+                  norm_topk_prob=False),
 ))
 
 PALIGEMMA_3B = _register(ModelConfig(

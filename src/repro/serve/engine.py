@@ -358,14 +358,14 @@ class ServeEngine:
         if self._insert_fn is None:
             cshard = to_shardings(self.cspecs, self.mesh)
 
-            def serve_insert(cache, k_rows, v_rows, slot):
-                k = jax.lax.dynamic_update_slice(
-                    cache["k"], k_rows.astype(cache["k"].dtype),
-                    (0, slot, 0, 0))
-                v = jax.lax.dynamic_update_slice(
-                    cache["v"], v_rows.astype(cache["v"].dtype),
-                    (0, slot, 0, 0))
-                return dict(cache, k=k, v=v)
+            def serve_insert(cache, rows, slot):
+                # every (L, 1, ..., S) leaf of a prefill's cache into the
+                # slot's row: K and V, or MLA's latent cache
+                return dict(cache, **{
+                    name: jax.lax.dynamic_update_slice(
+                        cache[name], r.astype(cache[name].dtype),
+                        (0, slot) + (0,) * (r.ndim - 2))
+                    for name, r in rows.items()})
 
             self._insert_fn = jax.jit(serve_insert, out_shardings=cshard,
                                       donate_argnums=(0,))
@@ -563,11 +563,10 @@ class ServeEngine:
         key trajectory across the batch and is reproducible per schedule,
         not per request).
         """
-        if (self.cfg.family in ("ssm", "hybrid") or self.cfg.mla
-                or self.cfg.frontend):
+        if self.cfg.family in ("ssm", "hybrid") or self.cfg.frontend:
             raise NotImplementedError(
-                "continuous batching requires the plain attention family "
-                "(ragged per-slot cache positions; modality-prefix "
+                "continuous batching requires an attention family, GQA or "
+                "MLA (ragged per-slot cache positions; modality-prefix "
                 "frontends would shift every slot's positions)")
         scfg = self.scfg
         reqs = [(np.asarray(p, np.int32).ravel(), int(m))
@@ -617,17 +616,21 @@ class ServeEngine:
                 with span("serve.admit", slot=j):
                     cache, tok, pos_b, active = self._insert(
                         cache, tok, pos_b, active, j, reqs[r][0])
-                slots[j] = {"req": r, "remaining": reqs[r][1]}
+                slots[j] = {"req": r, "remaining": reqs[r][1],
+                            "pos": reqs[r][0].size - 1}
             if all(s is None for s in slots):
                 t = arrivals[order[pi]]     # batch idle: skip to next arrival
                 continue
-            # one resident decode step advances every occupied slot
-            job = self._dispatch_begin()
-            with span("serve.decode"):
-                tok, pos_b, key, idx, cache = step_fn(
-                    self.params, cache, tok, pos_b, active, key, idx)
+            # one resident decode step advances every occupied slot; each
+            # attends its position + 1 cached positions (the host knows
+            # them, so the span's count costs no sync)
             live = [(j, s["req"]) for j, s in enumerate(slots)
                     if s is not None]
+            job = self._dispatch_begin()
+            with span("serve.decode", kv_positions=sum(
+                    slots[j]["pos"] + 1 for j, _ in live)):
+                tok, pos_b, key, idx, cache = step_fn(
+                    self.params, cache, tok, pos_b, active, key, idx)
             self._dispatch_end(job, tokens=len(live))
             step_log.append((tok, live))
             with span("serve.retire"):
@@ -635,6 +638,7 @@ class ServeEngine:
                     if s is None:
                         continue
                     s["remaining"] -= 1
+                    s["pos"] += 1
                     if s["remaining"] == 0:     # done-mask: retire the slot
                         slots[j] = None
                         free.append(j)
@@ -669,8 +673,8 @@ class ServeEngine:
             # program; tree staging sends it over the host link once
             _, pcache = self._get_prefill_fn()(self.params,
                                                self._put_replicated(padded))
-            cache = self._get_insert_fn()(cache, pcache["k"], pcache["v"],
-                                          np.int32(slot))
+            rows = {name: a for name, a in pcache.items() if name != "pos"}
+            cache = self._get_insert_fn()(cache, rows, np.int32(slot))
         tok = tok.at[slot, 0].set(int(prompt[-1]))
         self.stats["h2d_token_puts"] += 1   # the pending prompt token
         pos_b = pos_b.at[slot].set(s - 1)

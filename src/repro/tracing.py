@@ -6,9 +6,10 @@ an idle stretch of the device can be set against the span the host had
 open; with no profiler running it costs about a microsecond.  The offload
 path writes ``offload.submit`` (``verify``, ``plan``, ``stage``,
 ``launch``) and ``offload.wait`` (``fetch``); ``ServeEngine.generate_many``
-writes ``serve.admit``, ``serve.decode``, ``serve.retire`` and
-``serve.drain``.  Keep metadata to per-job spans and keep spans out of
-per-element loops.
+writes ``serve.admit`` (meta ``slot``), ``serve.decode`` (meta
+``kv_positions``: the cached positions the step's live slots attend, each
+slot's position + 1), ``serve.retire`` and ``serve.drain``.  Keep metadata
+to per-job spans and keep spans out of per-element loops.
 """
 
 from __future__ import annotations

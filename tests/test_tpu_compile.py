@@ -74,11 +74,12 @@ def test_kernel_compiles_for_v5e(kernel, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _compile_ragged_step(devices, b, s, monkeypatch):
+def _compile_ragged_step(devices, b, s, monkeypatch, cfg=None):
     """HLO text of the ragged serve step at smollm-360m's widths (2 layers,
-    a small vocabulary) for ``b`` slots of an ``s``-position cache, compiled
-    for the described chips ``devices`` as a (data, 1) mesh, the program
-    taking its TPU path as on the chip. -> (text, cfg)"""
+    a small vocabulary), or of ``cfg``, for ``b`` slots of an
+    ``s``-position cache, compiled for the described chips ``devices`` as a
+    (data, 1) mesh, the program taking its TPU path as on the chip.
+    -> (text, cfg)"""
     import dataclasses
 
     import numpy as np
@@ -89,8 +90,8 @@ def _compile_ragged_step(devices, b, s, monkeypatch):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "device_count", lambda: len(devices))
-    cfg = dataclasses.replace(M.get("smollm-360m"), n_layers=2,
-                              vocab_size=4096)
+    cfg = cfg or dataclasses.replace(M.get("smollm-360m"), n_layers=2,
+                                     vocab_size=4096)
     mesh = Mesh(np.asarray(devices).reshape(-1, 1), ("data", "model"))
     step, cspecs, tok = build_ragged_step(cfg, mesh, b, s, 0.0)
     here = NamedSharding(mesh, PartitionSpec())
@@ -114,24 +115,54 @@ def test_serve_decode_step_reads_the_cache_in_place(one_chip, monkeypatch):
     cache, compiled for a v5e: the kernel is there, and no other operation
     outputs an array of a layer's cache slab or larger, so no slab is
     copied, relayout, repeated or upcast."""
-    import re
-
-    import numpy as np
-
     b, s = 64, 2048
     text, cfg = _compile_ragged_step(list(one_chip.device_set), b, s,
                                      monkeypatch)
     assert "tpu_custom_call" in text
-    slab = b * s * cfg.n_kv_heads * cfg.head_dim
+    _assert_no_slab_sized_op(text, b * s * cfg.n_kv_heads * cfg.head_dim)
+
+
+def _assert_no_slab_sized_op(text, slab, positions=None):
+    """No operation of the compiled program but a passive one outputs an
+    array of ``slab`` elements or more (with a ``positions``-long axis,
+    where given: the weights may be larger than a slab)."""
+    import re
+
+    import numpy as np
+
     passive = {"parameter", "custom-call", "tuple", "get-tuple-element",
                "bitcast", "while", "call", "conditional", "opt-barrier"}
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(", line)
         if not m or m.group(2) in passive:
             continue
-        sizes = [int(np.prod([int(x) for x in dims.split(",") if x]))
-                 for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1))]
+        shapes = [[int(x) for x in dims.split(",") if x]
+                  for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1))]
+        sizes = [int(np.prod(sh)) for sh in shapes
+                 if positions is None or positions in sh]
         assert max(sizes, default=0) < slab, line[:160]
+
+
+def test_mla_decode_step_reads_the_latent_cache_in_place(one_chip,
+                                                         monkeypatch):
+    """The ragged serve step of deepseek-v2-lite-16b at its published widths
+    and depth, bfloat16 weights, 8 held experts, 40 slots of a 4096-position
+    latent cache: the latent kernel is there, and no operation copies,
+    converts, transposes or slices an array of positions the size of a
+    layer's cache slab or larger."""
+    import dataclasses
+
+    from repro import models as M
+
+    full = M.get("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(
+        full, param_dtype="bfloat16",
+        moe=dataclasses.replace(full.moe, experts_held=(0, 8)))
+    b, s = 40, 4096
+    text, cfg = _compile_ragged_step(list(one_chip.device_set), b, s,
+                                     monkeypatch, cfg)
+    assert "mla_decode_attention" in text and "tpu_custom_call" in text
+    _assert_no_slab_sized_op(text, b * s * cfg.kv_dim, positions=s)
 
 
 def test_serve_decode_step_compiles_across_chips(topo, monkeypatch):

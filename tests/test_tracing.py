@@ -120,3 +120,8 @@ def test_generate_many_writes_serve_spans(tmp_path):
     assert names[-1] == "serve.drain"
     slots = [m["slot"] for _, _, n, m in spans if n == "serve.admit"]
     assert slots == [0, 1, 1]
+    # each step's live slots attend position + 1 cells: prompts of 5 and 9
+    # at positions 4 and 8, then 5 and 9; the refill (3) at 2 beside the
+    # first at 6; the refill alone at 3
+    kv = [m["kv_positions"] for _, _, n, m in spans if n == "serve.decode"]
+    assert kv == [5 + 9, 6 + 10, 7 + 3, 4]
