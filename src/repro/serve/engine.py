@@ -59,6 +59,7 @@ from repro.core.fabric import (
 )
 from repro.core.policy import Staging, TenantKind, coerce_enum
 from repro.dist.sharding import batch_specs, cache_specs, param_specs, to_shardings
+from repro.kernels.decode_attention import positions_read
 from repro.models.config import ModelConfig
 from repro.models.model import (
     CallConfig, decode_step, decode_step_ragged, init_cache, init_params,
@@ -229,8 +230,9 @@ def build_ragged_step(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int,
         (next tok (B,1), pos_b', key', idx+1, cache').
     Each occupied slot writes/attends its own cache position (see
     ``decode_step_ragged``); free slots (``active == 0``) hold their
-    position so their writes stay confined to one stale cell, which the
-    next prefill-insert overwrites.
+    position but decode at position 0, so the kernel reads one chunk of
+    their rows and their writes land in cell 0, which the next
+    prefill-insert overwrites.
     """
     pspecs, cspecs, tok_sharding = (
         shardings or _serve_shardings(cfg, mesh, batch, max_len))
@@ -238,8 +240,8 @@ def build_ragged_step(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int,
     repl = NamedSharding(mesh, P())
 
     def serve_decode_step(params, cache, tok, pos_b, active, key, idx):
-        logits, cache = decode_step_ragged(params, cfg, cache, tok, pos_b,
-                                           call)
+        logits, cache = decode_step_ragged(
+            params, cfg, cache, tok, jnp.where(active > 0, pos_b, 0), call)
         lg = logits[:, 0] if logits.ndim == 3 else logits
         key = jax.random.fold_in(key, idx)
         nxt = sample(lg, key)
@@ -622,13 +624,15 @@ class ServeEngine:
                 t = arrivals[order[pi]]     # batch idle: skip to next arrival
                 continue
             # one resident decode step advances every occupied slot; each
-            # attends its position + 1 cached positions (the host knows
-            # them, so the span's count costs no sync)
+            # attends its position + 1 cached positions, and the kernel
+            # copies them in whole chunks (the host knows the positions, so
+            # the span's counts cost no sync)
             live = [(j, s["req"]) for j, s in enumerate(slots)
                     if s is not None]
+            at = [slots[j]["pos"] for j, _ in live]
             job = self._dispatch_begin()
-            with span("serve.decode", kv_positions=sum(
-                    slots[j]["pos"] + 1 for j, _ in live)):
+            with span("serve.decode", kv_positions=sum(at) + len(at),
+                      kv_read_positions=positions_read(at, scfg.max_len)):
                 tok, pos_b, key, idx, cache = step_fn(
                     self.params, cache, tok, pos_b, active, key, idx)
             self._dispatch_end(job, tokens=len(live))

@@ -8,8 +8,10 @@ path writes ``offload.submit`` (``verify``, ``plan``, ``stage``,
 ``launch``) and ``offload.wait`` (``fetch``); ``ServeEngine.generate_many``
 writes ``serve.admit`` (meta ``slot``), ``serve.decode`` (meta
 ``kv_positions``: the cached positions the step's live slots attend, each
-slot's position + 1), ``serve.retire`` and ``serve.drain``.  Keep metadata
-to per-job spans and keep spans out of per-element loops.
+slot's position + 1; ``kv_read_positions``: those the decode kernel copies,
+each slot's rounded up to whole chunks), ``serve.retire`` and
+``serve.drain``.  Keep metadata to per-job spans and keep spans out of
+per-element loops.
 """
 
 from __future__ import annotations

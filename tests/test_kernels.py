@@ -7,6 +7,7 @@ import jax.numpy as jnp
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
+from repro.kernels.decode_attention import chunk_len, mla_decode_attention
 
 RNG = np.random.default_rng(42)
 
@@ -113,22 +114,34 @@ def test_flash_blocks_sweep():
                                    rtol=2e-3, atol=2e-3)
 
 
+def _positions(b, s):
+    """0, the last cell, past the end (-> the last cell), mid-way; where the
+    cache holds several chunks, either side of the first chunk's end and a
+    row whose read is one chunk."""
+    pos = [0, s - 1, s, s // 3]
+    ch = chunk_len(s)
+    if ch < s:
+        pos += [ch - 1, ch, ch + 1, 7]
+    assert len(pos) == b
+    return jnp.asarray(pos, jnp.int32)
+
+
 @pytest.mark.parametrize("b,hq,hkv,d,s", [(4, 6, 2, 32, 256),
                                           (4, 4, 4, 16, 64),
-                                          (4, 8, 1, 64, 200)])
+                                          (4, 8, 1, 64, 200),
+                                          (8, 6, 2, 32, 1536)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention(b, hq, hkv, d, s, dtype):
     """Grouped-query decode against a stacked cache: the kernel's output
-    matches the oracle, and each row's token lands at its position (0, the
-    last cell, past the end -> the last cell, mid-way) of the given layer
-    and nowhere else."""
+    matches the oracle, and each row's token lands at its position
+    (``_positions``) of the given layer and nowhere else."""
     L, layer = 3, 1
     q = jnp.asarray(RNG.standard_normal((b, hq, d)), dtype)
     k = jnp.asarray(RNG.standard_normal((b, hkv, d)), dtype)
     v = jnp.asarray(RNG.standard_normal((b, hkv, d)), dtype)
     kc = jnp.asarray(RNG.standard_normal((L, b, hkv * d, s)), dtype)
     vc = jnp.asarray(RNG.standard_normal((L, b, hkv * d, s)), dtype)
-    pos = jnp.asarray([0, s - 1, s, s // 3], jnp.int32)
+    pos = _positions(b, s)
     got = ops.decode_attention(q, k, v, kc, vc, pos, jnp.int32(layer),
                                impl="pallas")
     want = ref.decode_attention(q, k, v, kc, vc, pos, jnp.int32(layer))
@@ -144,6 +157,54 @@ def test_decode_attention(b, hq, hkv, d, s, dtype):
                                       np.asarray(cache)[~hit])
         np.testing.assert_array_equal(np.asarray(new)[layer, np.arange(b), :, at],
                                       np.asarray(row).reshape(b, -1))
+
+
+@pytest.mark.parametrize("mode", ["gqa", "latent"])
+def test_decode_attention_reads_only_the_live_chunks(mode):
+    """Every cache cell past each row's copied chunks, and every other
+    layer, is NaN.  Short rows follow long ones in the same VMEM slot, so
+    stale VMEM is live past their prefix (and the first row's is unwritten,
+    NaN in the interpreter): the output is finite and matches the oracle on
+    the same cache with those cells zeroed, and the cache is unchanged but
+    for each row's written column."""
+    L, layer, s, d = 3, 1, 1536, 32
+    ch = chunk_len(s)
+    pos = np.asarray([3, 1535, 1400, 10, 600, 0], np.int32)
+    b = len(pos)
+    kvd = 2 * d
+    caches = [RNG.standard_normal((L, b, kvd, s)).astype(np.float32)
+              for _ in range(2 if mode == "gqa" else 1)]
+    dead = np.ones(caches[0].shape, bool)
+    for i, n in enumerate(-(-(pos + 1) // ch) * ch):
+        dead[layer, i, :, :n] = False
+    poisoned = [np.where(dead, np.nan, c).astype(np.float32) for c in caches]
+    zeroed = [jnp.asarray(np.where(dead, 0, c)) for c in caches]
+    pos_j = jnp.asarray(pos)
+    if mode == "gqa":
+        q = jnp.asarray(RNG.standard_normal((b, 4, d)), jnp.float32)
+        k = jnp.asarray(RNG.standard_normal((b, 2, d)), jnp.float32)
+        v = jnp.asarray(RNG.standard_normal((b, 2, d)), jnp.float32)
+        got = ops.decode_attention(q, k, v, *map(jnp.asarray, poisoned),
+                                   pos_j, jnp.int32(layer), impl="pallas")
+        want = ref.decode_attention(q, k, v, *zeroed, pos_j,
+                                    jnp.int32(layer))
+        cols = [k.reshape(b, -1), v.reshape(b, -1)]
+    else:
+        q = jnp.asarray(RNG.standard_normal((b, 4, kvd)), jnp.float32)
+        kv = jnp.asarray(RNG.standard_normal((b, kvd)), jnp.float32)
+        got = mla_decode_attention(q, kv, jnp.asarray(poisoned[0]), pos_j,
+                                   layer, scale=0.2, v_dim=d)
+        want = ref.mla_decode_attention(q, kv, zeroed[0], pos_j, layer,
+                                        scale=0.2, v_dim=d)
+        cols = [kv]
+    o = np.asarray(got[0])
+    assert np.isfinite(o).all()
+    np.testing.assert_allclose(o, np.asarray(want[0]), rtol=2e-4, atol=2e-4)
+    at = np.minimum(pos, s - 1)
+    for old, new, col in zip(poisoned, got[1:], cols):
+        expect = old.copy()
+        expect[layer, np.arange(b), :, at] = np.asarray(col)
+        np.testing.assert_array_equal(np.asarray(new), expect)
 
 
 @given(n=st.integers(1, 2048))

@@ -9,6 +9,8 @@ and by the order of their sums: the absorbed decode contracts ``W_UK``
 into the query, the reference contracts it into the keys.
 """
 
+import collections
+import contextlib
 import dataclasses
 import math
 
@@ -131,17 +133,31 @@ def test_held_expert_shares_add_up_to_the_layer():
     assert np.abs(renormed - routed).max() > 0.1 * np.abs(routed).max()
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_latent_kernel_matches_its_xla_form(dtype):
+#: (cache length, row positions): one chunk of 256; the slab as one chunk
+#: where 128 does not divide it; three chunks of 512
+LATENT_CASES = {"": (256, (0, 255, 100, 256)),
+                "-s200": (200, (0, 199, 66, 200)),
+                "-s1536": (1536, (511, 512, 513, 0, 1535, 7, 1536))}
+
+
+@pytest.mark.parametrize("dtype,s,positions", [
+    pytest.param(dtype, *case, id=dtype + name)
+    for name, case in LATENT_CASES.items()
+    for dtype in ("float32", "bfloat16")])
+def test_latent_kernel_matches_its_xla_form(dtype, s, positions):
     """Interpret mode against ``kernels/ref.py``: rows at position 0, the
-    last cell, mid-way, and parked past the end."""
-    b, h, r, rho, s, L = 4, 4, 32, 16, 256, 3
+    last cell, mid-way, and parked past the end; a cache of no whole
+    number of 128 positions (one chunk, the slab); and in a cache of
+    several chunks, rows either side of the first chunk's end and a row
+    whose read is one chunk."""
+    h, r, rho, L = 4, 32, 16, 3
+    b = len(positions)
     dt = jnp.dtype(dtype)
     kq, kk, kc = jax.random.split(jax.random.key(3), 3)
     q = jax.random.normal(kq, (b, h, r + rho)).astype(dt)
     kv = jax.random.normal(kk, (b, r + rho)).astype(dt)
     cache = jax.random.normal(kc, (L, b, r + rho, s)).astype(dt)
-    pos_b = jnp.asarray([0, s - 1, 100, s], jnp.int32)
+    pos_b = jnp.asarray(positions, jnp.int32)
     got_o, got_c = mla_decode_attention(q, kv, cache, pos_b, 1, scale=0.17,
                                         v_dim=r, interpret=True)
     want_o, want_c = kref.mla_decode_attention(q, kv, cache, pos_b, 1,
@@ -172,9 +188,12 @@ def test_prefill_logits_match_reference():
 
 @pytest.mark.parametrize("path", ["xla", "pallas"])
 def test_generate_many_decodes_as_the_reference(path, monkeypatch):
-    """Staggered arrivals into 3 slots; every decode step's logits of every
-    live slot against the reference's full forward over that request's
-    prompt and served tokens."""
+    """Staggered arrivals into 3 slots, with retired slots between
+    requests; every decode step's logits of every live slot against the
+    reference's full forward over that request's prompt and served tokens.
+    Free slots decode at position 0, and each step's span counts the
+    positions its live slots attend and the whole 128-position chunks the
+    kernel copies for them (one request crosses a chunk's end)."""
     if path == "pallas":    # the latent kernel, in interpret mode
         monkeypatch.setattr(ops, "_resolve", lambda impl: "pallas")
     cfg = _cfg()
@@ -189,12 +208,19 @@ def test_generate_many_decodes_as_the_reference(path, monkeypatch):
         return logits, cache
 
     monkeypatch.setattr(serve_engine, "decode_step_ragged", recording)
+    spans = []
+
+    def span(name, **meta):
+        spans.append((name, meta))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(serve_engine, "span", span)
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
                 ("data", "model"))
     eng = ServeEngine(cfg, params, mesh,
-                      ServeConfig(batch=3, max_len=48, prefill_bucket=8))
+                      ServeConfig(batch=3, max_len=384, prefill_bucket=8))
     rng = np.random.default_rng(4)
-    lens, news = [1, 9, 14, 5, 20, 11], [6, 4, 9, 7, 3, 8]
+    lens, news = [1, 9, 14, 5, 130, 11], [6, 4, 9, 7, 3, 8]
     reqs = [(rng.integers(0, cfg.vocab_size, n).astype(np.int32), m)
             for n, m in zip(lens, news)]
     n_calls = [0]
@@ -223,6 +249,19 @@ def test_generate_many_decodes_as_the_reference(path, monkeypatch):
     assert len(steps) == n_calls[0]
 
     checked = 0
+    live = collections.defaultdict(dict)        # step -> {slot: position}
+    for t0, slot, r in admitted:
+        for i in range(reqs[r][1]):
+            live[t0 + i][slot] = lens[r] - 1 + i
+    decode = [meta for name, meta in spans if name == "serve.decode"]
+    assert len(decode) == len(steps)
+    for t, (meta, (_, pb)) in enumerate(zip(decode, steps)):
+        at = live[t]
+        assert all(pb[j] == 0 for j in range(3) if j not in at)
+        assert meta["kv_positions"] == sum(p + 1 for p in at.values())
+        assert meta["kv_read_positions"] == sum(
+            -(-(p + 1) // 128) * 128 for p in at.values())
+    assert any(p >= 128 for at in live.values() for p in at.values())
     for t0, slot, r in admitted:
         prompt, m = reqs[r]
         seq = np.concatenate([prompt, outs[r][:-1]])

@@ -1,4 +1,17 @@
-"""Continuous batching (generate_many) + sub-batch padding (subprocess)."""
+"""Continuous batching (generate_many) + sub-batch padding (subprocess),
+and the decode kernel's path through generate_many (in process)."""
+
+import contextlib
+import dataclasses
+
+import jax
+import numpy as np
+from jax.sharding import Mesh
+
+from repro import models as M
+from repro.kernels import ops
+from repro.serve import ServeConfig, ServeEngine
+from repro.serve import engine as serve_engine
 
 
 def test_subbatch_padding_matches_full_batch(subproc):
@@ -106,3 +119,53 @@ for x, y in zip(a, b):
     assert (x >= 0).all() and (x < cfg.vocab_size).all()
 print("OK")
 """, devices=8, x64=False, timeout=900)
+
+
+def test_generate_many_kernel_path_keeps_greedy_tokens(monkeypatch):
+    """The decode kernel (interpret mode) through generate_many on one
+    device: with slots retired and refilled, free slots decoding at
+    position 0 and a request crossing a 128-position chunk, every request
+    emits the greedy tokens it emits alone on the XLA path, and each step's
+    span counts the whole chunks its live slots' prefixes take."""
+    cfg = dataclasses.replace(M.reduced(M.get("smollm-360m")),
+                              param_dtype="float32", compute_dtype="float32")
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    params = M.init_params(jax.random.key(0), cfg)
+    scfg = ServeConfig(batch=3, max_len=384, prefill_bucket=8)
+    rng = np.random.default_rng(11)
+    lens, news = [9, 1, 126, 14, 5, 7], [4, 6, 5, 3, 7, 2]
+    reqs = [(rng.integers(0, cfg.vocab_size, n).astype(np.int32), m)
+            for n, m in zip(lens, news)]
+    alone = [ServeEngine(cfg, params, mesh, scfg).generate_many([r])[0]
+             for r in reqs]
+
+    monkeypatch.setattr(ops, "_resolve", lambda impl: "pallas")
+    spans = []
+
+    def span(name, **meta):
+        spans.append((name, meta))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(serve_engine, "span", span)
+    eng = ServeEngine(cfg, params, mesh, scfg)
+    outs = eng.generate_many(reqs, arrival_steps=[0, 0, 0, 1, 2, 6])
+    for got, want in zip(outs, alone):
+        np.testing.assert_array_equal(got, want)
+    assert eng.stats["requests_retired"] == len(reqs)
+
+    # the schedule: admissions in arrival order into the lowest free slot,
+    # a slot free again the step after its last token
+    free_at, live = [0] * 3, []                 # live: (first step, r)
+    for r, arrive in enumerate([0, 0, 0, 1, 2, 6]):
+        j = min(range(3), key=lambda j: (max(free_at[j], arrive), j))
+        t0 = max(free_at[j], arrive)
+        live.append((t0, r))
+        free_at[j] = t0 + news[r]
+    read = [m["kv_read_positions"] for n, m in spans if n == "serve.decode"]
+    want = []
+    for t in range(len(read)):
+        pos = [lens[r] - 1 + t - t0 for t0, r in live
+               if t0 <= t < t0 + news[r]]
+        want.append(sum(-(-(p + 1) // 128) * 128 for p in pos))
+    assert read == want
+    assert max(want) > 3 * 128       # request 2 reads a second chunk

@@ -125,3 +125,7 @@ def test_generate_many_writes_serve_spans(tmp_path):
     # first at 6; the refill alone at 3
     kv = [m["kv_positions"] for _, _, n, m in spans if n == "serve.decode"]
     assert kv == [5 + 9, 6 + 10, 7 + 3, 4]
+    # the kernel copies whole chunks: a 32-position cache is one chunk
+    read = [m["kv_read_positions"] for _, _, n, m in spans
+            if n == "serve.decode"]
+    assert read == [2 * 32, 2 * 32, 2 * 32, 32]
